@@ -27,6 +27,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import (MPHX, PAPER_TABLE2, SprayConfig, table2,  # noqa: E402
                         table2_topologies)
 from repro.core.dragonfly import frontier_flattening_example  # noqa: E402
@@ -126,15 +127,14 @@ def bench_traffic():
 
 
 def bench_collectives():
-    """Wall-time the JAX multi-plane collectives on an 8-device host mesh
-    (subprocess, to keep this process at 1 device)."""
+    """Wall-time the JAX multi-plane collectives on an 8-device CPU host
+    mesh, in a child pinned to the CPU platform (so this process keeps
+    one device, and the child never asks for a chip)."""
     import subprocess
 
     code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import time, jax, jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.collectives import multiplane_psum, decomposed_psum, psum_auto
 mesh = jax.make_mesh((8,), ("model",))
@@ -153,7 +153,8 @@ for name, fn in [
     r.block_until_ready()
     print(f"BENCH {name} {(time.perf_counter()-t0)/20*1e6:.1f}")
 """
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
@@ -161,7 +162,7 @@ for name, fn in [
         if line.startswith("BENCH"):
             _, name, us = line.split()
             emit(f"collectives/{name}", float(us),
-                 "8_host_devices;2MB_payload")
+                 "8_cpu_host_devices;2MB_payload")
     if proc.returncode != 0:
         emit("collectives/error", 0.0, proc.stderr[-120:].replace(",", ";"))
 
@@ -1021,6 +1022,7 @@ def main(argv: "list[str] | None" = None) -> int:
                    "path never crosses the simulator leave explicit "
                    "skip records (docs/observability.md)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     which = args.benches or list(BENCHES)
     unknown = [n for n in which if n not in BENCHES]
     if unknown:

@@ -4,7 +4,8 @@ checkpoint onto the smaller mesh, and continue training — loss continues
 from where it left off.
 
 Run:  PYTHONPATH=src python examples/elastic_restart.py
-(re-execs itself with XLA_FLAGS to get 8 host devices)
+(re-execs itself pinned to the CPU platform with 8 host devices; the
+demo exists only on host devices and never asks for an accelerator)
 """
 
 import os
@@ -16,6 +17,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 if os.environ.get("_MPHX_ELASTIC_CHILD") != "1":
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["_MPHX_ELASTIC_CHILD"] = "1"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
@@ -28,6 +30,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.base import ModelConfig, RunConfig  # noqa: E402
 from repro.data.pipeline import DataConfig, SyntheticDataset  # noqa: E402
+from repro.launch.mesh import make_auto_mesh  # noqa: E402
 from repro.models.sharding import MeshPlan  # noqa: E402
 from repro.models.transformer import DecoderLM  # noqa: E402
 from repro.train.checkpoint import Checkpointer  # noqa: E402
@@ -46,13 +49,14 @@ CFG = ModelConfig(arch_id="t", family="dense", n_layers=2, d_model=64,
 
 
 def main():
-    print(f"devices: {jax.device_count()}")
+    print(f"devices: {jax.device_count()} x {jax.default_backend()} "
+          "(host devices; JAX_PLATFORMS=cpu)")
     run = RunConfig(lr=3e-3, warmup_steps=5, total_steps=40)
     ds = SyntheticDataset(DataConfig(vocab_size=256, seq_len=32,
                                      global_batch=8, temperature=0.25))
 
     # phase 1: healthy cluster, 4x2 mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     trainer = make(mesh, run)
     state = jax.device_put(trainer.init_state(jax.random.PRNGKey(0)),
                            trainer.state_shardings())
@@ -70,7 +74,7 @@ def main():
           f"{plan.new_shape} (usable {plan.hosts_used})")
 
     # phase 2: restore the SAME checkpoint onto the smaller mesh
-    mesh2 = jax.make_mesh(plan.new_shape, plan.axis_names)
+    mesh2 = make_auto_mesh(plan.new_shape, plan.axis_names)
     trainer2 = make(mesh2, run)
     template = jax.eval_shape(
         lambda: trainer2.init_state(jax.random.PRNGKey(0)))

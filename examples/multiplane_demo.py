@@ -4,7 +4,8 @@ single-psum oracle; then models the same collectives on the paper's
 topologies with the flow-level simulator.
 
 Run:  PYTHONPATH=src python examples/multiplane_demo.py
-(re-execs itself with XLA_FLAGS to get 8 host devices)
+(re-execs itself pinned to the CPU platform with 8 host devices; the
+demo exists only on host devices and never asks for an accelerator)
 """
 
 import os
@@ -15,6 +16,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 if os.environ.get("_MPHX_DEMO_CHILD") != "1":
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["_MPHX_DEMO_CHILD"] = "1"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
@@ -23,8 +25,8 @@ if os.environ.get("_MPHX_DEMO_CHILD") != "1":
 sys.path.insert(0, SRC)
 
 import jax  # noqa: E402
-from repro.compat import shard_map
 import jax.numpy as jnp  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core import MPHX, table2_topologies  # noqa: E402
@@ -35,7 +37,8 @@ from repro.core.netsim import allreduce_time  # noqa: E402
 
 
 def device_demo():
-    print(f"devices: {jax.device_count()}")
+    print(f"devices: {jax.device_count()} x {jax.default_backend()} "
+          "(host devices; JAX_PLATFORMS=cpu)")
     mesh = jax.make_mesh((2, 4), ("data", "model"))
     x = jnp.linspace(-1, 1, 8 * 1024 * 4).reshape(8, 1024, 4)
 

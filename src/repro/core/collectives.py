@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
-
 
 def plane_chunk_count(size: int, n_planes: int) -> int:
     """Number of per-plane chunks a sprayed collective splits into: the
@@ -65,7 +63,7 @@ def decomposed_psum(x, axis_name: str, split_axis: int = 0):
     scheduler separately (overlap the all-gather with downstream compute).
     Requires ``x.shape[split_axis]`` divisible by the axis size.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if x.shape[split_axis] % n:
         return lax.psum(x, axis_name)
     scattered = lax.psum_scatter(x, axis_name, scatter_dimension=split_axis,
@@ -84,7 +82,7 @@ def hierarchical_psum(x, axis_names: Sequence[str], split_axis: int = 0):
     if len(axis_names) == 1:
         return decomposed_psum(x, axis_names[0], split_axis)
     a0 = axis_names[0]
-    n = axis_size(a0)
+    n = lax.axis_size(a0)
     if x.shape[split_axis] % n:
         # fall back: reduce this axis whole, recurse on the rest
         return hierarchical_psum(lax.psum(x, a0), axis_names[1:], split_axis)

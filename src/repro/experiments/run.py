@@ -36,8 +36,10 @@ import argparse
 import sys
 from contextlib import nullcontext
 
+from repro.compile_cache import enable_compile_cache
 from repro.routing.protection import REROUTE_MODES
 from repro.sim.failures import parse_failure_spec
+from repro.sim.fairshare import resolve_sim_backend
 from .cosuite import (DEFAULT_COSIM_CONFIGS, DEFAULT_COSIM_RANKS,
                       DEFAULT_COSIM_TOPOS, run_cosim_suite)
 from .scenarios import SCENARIOS
@@ -93,11 +95,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="sim suite: measured-collective payload per NIC")
     p.add_argument("--sim-backend",
                    choices=["numpy", "jax", "pallas", "auto"],
-                   default="numpy",
-                   help="sim/sweep suites: fair-share solver path — "
-                   "numpy reference loop, jax in-jit while_loop, pallas "
-                   "segment kernels (repro.sim.fairshare); jax/pallas "
-                   "make the 65K-NIC presets tractable")
+                   default="auto",
+                   help="sim/sweep/serving suites: fair-share solver path "
+                   "— numpy reference loop, jax in-jit while_loop, pallas "
+                   "segment kernels (CPU only), or auto: jax on a TPU, "
+                   "else numpy unless x64 is on (repro.sim.fairshare)")
     p.add_argument("--failures", nargs="+", default=None,
                    metavar="SPEC",
                    help="failure specs for the failures suite, e.g. "
@@ -164,7 +166,12 @@ def _note_if_untraced(rec, suite: str, n_before: int, reason: str) -> None:
 
 def main(argv: "list[str] | None" = None) -> int:
     args = build_parser().parse_args(argv)
-    rc = 0
+    enable_compile_cache()
+    try:
+        args.sim_backend = resolve_sim_backend(args.sim_backend)
+    except ValueError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
     if args.failures is not None:
         try:
             specs = [parse_failure_spec(s) for s in args.failures]

@@ -97,7 +97,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: int | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """q: (B, H, Sq, Dh); k, v: (B, K, Skv, Dh); GQA via H % K == 0."""
     B, H, Sq, Dh = q.shape
     K, Skv = k.shape[1], k.shape[2]

@@ -3,8 +3,8 @@
 Accepts the model-layout tensors q (B, S, K, G, Dh), k/v (B, S, K, Dh)
 (as produced by ``repro.models.layers.mha_project_qkv``) and handles the
 transpose to kernel layout, dtype preservation, and block-size selection.
-``interpret=True`` is the validated CPU path; on real TPU pass
-``interpret=False``.
+``interpret`` has no default: ``True`` on the CPU (the Pallas
+interpreter), ``False`` on a TPU (Mosaic).
 """
 
 from __future__ import annotations
@@ -22,7 +22,7 @@ from .kernel import flash_attention
 def flash_attention_model_layout(q, k, v, *, causal: bool = True,
                                  window: int | None = None,
                                  block_q: int = 128, block_k: int = 128,
-                                 interpret: bool = True):
+                                 interpret: bool):
     """q: (B, S, K, G, Dh); k, v: (B, S, K, Dh) -> (B, S, K, G, Dh)."""
     B, S, K, G, Dh = q.shape
     qk = q.transpose(0, 2, 3, 1, 4).reshape(B, K * G, S, Dh)
@@ -39,7 +39,7 @@ def flash_attention_model_layout(q, k, v, *, causal: bool = True,
 def flash_attention_kernel_layout(q, k, v, *, causal: bool = True,
                                   window: int | None = None,
                                   block_q: int = 128, block_k: int = 128,
-                                  interpret: bool = True):
+                                  interpret: bool):
     """q: (B, H, Sq, Dh); k, v: (B, K, Skv, Dh)."""
     return flash_attention(q, k, v, causal=causal, window=window,
                            block_q=block_q, block_k=block_k,

@@ -40,7 +40,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc, *, n_k: int):
 
 
 def grouped_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
-                   block_k: int = 128, interpret: bool = True):
+                   block_k: int = 128, interpret: bool):
     """x: (E, M, K); w: (E, K, N) -> (E, M, N)."""
     E, M, K = x.shape
     _, _, N = w.shape
@@ -92,7 +92,7 @@ def _ragged_kernel(gid_ref, start_ref, size_ref, x_ref, w_ref, o_ref, acc,
 
 
 def ragged_grouped_matmul(x, w, group_sizes, *, block_m: int = 128,
-                          block_k: int = 128, interpret: bool = True):
+                          block_k: int = 128, interpret: bool):
     """x: (T, K) rows sorted by group; w: (E, K, N); group_sizes: (E,).
 
     The block->group table is a scalar-prefetch operand: the w BlockSpec's

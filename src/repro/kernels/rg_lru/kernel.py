@@ -38,7 +38,7 @@ def _lru_kernel(a_ref, b_ref, h0_ref, y_ref, h_scr, *, chunk: int):
 
 
 def lru_scan(a, b, h0=None, *, chunk: int = 128, block_w: int = 512,
-             interpret: bool = True):
+             interpret: bool):
     """a, b: (B, S, W) float32; h0 (B, W) -> (y (B,S,W), h_last (B,W)).
 
     The final state is returned by reading the last time row of y.

@@ -13,7 +13,7 @@ from .kernel import lru_scan
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_w", "interpret"))
 def rg_lru_pallas(params, x, h0=None, *, chunk: int = 128,
-                  block_w: int = 512, interpret: bool = True):
+                  block_w: int = 512, interpret: bool):
     """Drop-in replacement for repro.models.rglru.rg_lru_scan (fwd only)."""
     a, bcoef, _ = _rg_lru_coeffs(params, x)
     return lru_scan(a, bcoef, h0, chunk=chunk, block_w=block_w,

@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float, d: int):
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6, block_n: int = 256,
-            interpret: bool = True):
+            interpret: bool):
     """x: (N, D); scale: (D,)."""
     N, D = x.shape
     bn = min(block_n, N)

@@ -8,18 +8,21 @@ rounds in two sparse reductions over the coalesced COO incidence arrays:
 * ``segment_min``  — per-flow bottleneck ``min_e cap[e]/frac[f,e]``.
 
 Both are scatter reductions with data-dependent indices, which TPUs hate
-in their natural form.  The kernels below recast them as **one-hot
-contractions**: the grid tiles (segment blocks x entry blocks), each step
-builds a ``(block_nnz, block_seg)`` one-hot mask of which entries land in
-this segment tile and reduces it on the VPU/MXU, accumulating into the
-resident output tile across the entry-block axis (the classic Pallas
-revisiting-output accumulation pattern; the entry axis is innermost so
-each output tile is initialized once at entry-block 0 and stays in VMEM).
+in their natural form.  The kernels below recast them as **masked
+compares on (8, 128) tiles**: the (NNZ,) entries are padded and laid out
+as ``(rows, 128)`` lanes, the grid tiles (segment blocks x row blocks),
+and each step sweeps its rows, comparing a row's 128 segment ids against
+a ``(block_seg, 128)`` tile whose sublanes are this block's segments.
+Matching entries fold into a lane-partial ``(block_seg, 128)`` output
+tile that stays resident in VMEM across the row-block axis (the entry
+axis is innermost, so each tile is initialized once at row-block 0); one
+XLA reduction over the 128 lanes finishes the job.
 
 Entries are padded with an out-of-range segment id, so padding never hits
-a real bin.  ``interpret=True`` (the default) runs the same kernel on CPU
-via the Pallas interpreter at float64 — the cross-validation fallback the
-test layer uses; pass ``interpret=False`` on a real TPU.
+a real bin.  ``interpret`` has no default: callers pick it from the
+platform (``True`` on the CPU, where the Pallas interpreter runs the same
+kernel at float64; ``False`` on a TPU, where Mosaic compiles it — float32
+only, Mosaic has no float64).
 """
 
 from __future__ import annotations
@@ -30,85 +33,78 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _onehot(ids, lo, block_seg: int, dtype):
-    """(bn, block_seg) mask of entries whose segment falls in this tile."""
-    local = ids - lo
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_seg), 1)
-    return (local[:, None] == iota).astype(dtype)
+LANES = 128
 
 
-def _segment_sum_kernel(ids_ref, val_ref, o_ref, *, block_seg: int):
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _segment_kernel(ids_ref, val_ref, o_ref, *, combine, fill):
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.full(o_ref.shape, fill, o_ref.dtype)
 
-    ids = ids_ref[...]
-    vals = val_ref[...]
-    hot = _onehot(ids, pl.program_id(0) * block_seg, block_seg, vals.dtype)
-    o_ref[...] += (vals[:, None] * hot).sum(axis=0)
+    block_seg = o_ref.shape[0]
+    seg = (pl.program_id(0) * block_seg
+           + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0))
 
+    def row(r, acc):
+        ids = ids_ref[pl.ds(r, 1), :]
+        vals = val_ref[pl.ds(r, 1), :]
+        return combine(acc, jnp.where(ids == seg, vals, fill))
 
-def _segment_min_kernel(ids_ref, val_ref, o_ref, *, block_seg: int):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = jnp.full_like(o_ref, jnp.inf)
-
-    ids = ids_ref[...]
-    vals = val_ref[...]
-    hot = _onehot(ids, pl.program_id(0) * block_seg, block_seg,
-                  vals.dtype) > 0
-    cand = jnp.where(hot, vals[:, None], jnp.inf).min(axis=0)
-    o_ref[...] = jnp.minimum(o_ref[...], cand)
+    o_ref[...] = jax.lax.fori_loop(0, ids_ref.shape[0], row, o_ref[...])
 
 
-def _pad_coo(values, segment_ids, num_segments: int, block_nnz: int):
-    n = values.shape[0]
-    bn = max(min(block_nnz, n), 1)
-    pad = (-n) % bn if n else bn
-    if pad:
-        values = jnp.pad(values, (0, pad))
-        # out-of-range id: the padded entries miss every segment tile
-        segment_ids = jnp.pad(segment_ids, (0, pad),
-                              constant_values=num_segments)
-    return values, segment_ids.astype(jnp.int32), bn
+def _pad_coo(values, segment_ids, num_segments: int, block_rows: int):
+    """``(rows, 128)`` values and int32 ids, rows a multiple of the row
+    block (itself a multiple of 8), padded with id ``num_segments``."""
+    rows = _round_up(max(values.shape[0], 1), LANES) // LANES
+    br = min(block_rows, _round_up(rows, 8))
+    pad = _round_up(rows, br) * LANES - values.shape[0]
+    values = jnp.pad(values, (0, pad)).reshape(-1, LANES)
+    # out-of-range id: the padded entries miss every real segment
+    ids = jnp.pad(segment_ids.astype(jnp.int32), (0, pad),
+                  constant_values=num_segments).reshape(-1, LANES)
+    return values, ids, br
 
 
-def _segment_call(kernel, values, segment_ids, num_segments: int,
-                  block_nnz: int, block_seg: int, interpret: bool):
-    values, ids, bn = _pad_coo(values, segment_ids, num_segments, block_nnz)
-    bs = max(min(block_seg, num_segments), 1)
-    ps = (-num_segments) % bs
-    grid = ((num_segments + ps) // bs, values.shape[0] // bn)
+def _segment_call(combine, fill, values, segment_ids, num_segments: int,
+                  block_rows: int, block_seg: int, interpret: bool):
+    values, ids, br = _pad_coo(values, segment_ids, num_segments,
+                               block_rows)
+    bs = min(block_seg, _round_up(num_segments, 8))
+    n_seg_blocks = _round_up(num_segments, bs) // bs
     out = pl.pallas_call(
-        functools.partial(kernel, block_seg=bs),
-        grid=grid,
+        functools.partial(_segment_kernel, combine=combine, fill=fill),
+        grid=(n_seg_blocks, ids.shape[0] // br),
         in_specs=[
-            pl.BlockSpec((bn,), lambda s, i: (i,)),
-            pl.BlockSpec((bn,), lambda s, i: (i,)),
+            pl.BlockSpec((br, LANES), lambda s, i: (i, 0)),
+            pl.BlockSpec((br, LANES), lambda s, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bs,), lambda s, i: (s,)),
-        out_shape=jax.ShapeDtypeStruct((num_segments + ps,), values.dtype),
+        out_specs=pl.BlockSpec((bs, LANES), lambda s, i: (s, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_seg_blocks * bs, LANES),
+                                       values.dtype),
         interpret=interpret,
     )(ids, values)
     return out[:num_segments]
 
 
-def segment_sum(values, segment_ids, num_segments: int, *,
-                block_nnz: int = 1024, block_seg: int = 512,
-                interpret: bool = True):
+def segment_sum(values, segment_ids, num_segments: int, *, interpret: bool,
+                block_rows: int = 512, block_seg: int = 256):
     """Scatter-add ``values`` (NNZ,) into ``num_segments`` bins."""
     if num_segments == 0:
         return jnp.zeros((0,), dtype=values.dtype)
-    return _segment_call(_segment_sum_kernel, values, segment_ids,
-                         num_segments, block_nnz, block_seg, interpret)
+    return _segment_call(jnp.add, 0.0, values, segment_ids, num_segments,
+                         block_rows, block_seg, interpret).sum(axis=1)
 
 
-def segment_min(values, segment_ids, num_segments: int, *,
-                block_nnz: int = 1024, block_seg: int = 512,
-                interpret: bool = True):
+def segment_min(values, segment_ids, num_segments: int, *, interpret: bool,
+                block_rows: int = 512, block_seg: int = 256):
     """Per-segment min of ``values`` (NNZ,); empty segments hold +inf."""
     if num_segments == 0:
         return jnp.zeros((0,), dtype=values.dtype)
-    return _segment_call(_segment_min_kernel, values, segment_ids,
-                         num_segments, block_nnz, block_seg, interpret)
+    return _segment_call(jnp.minimum, jnp.inf, values, segment_ids,
+                         num_segments, block_rows, block_seg,
+                         interpret).min(axis=1)

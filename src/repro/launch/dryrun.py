@@ -36,7 +36,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import cost_analysis  # noqa: E402
 from repro.configs.base import LM_SHAPES, RunConfig  # noqa: E402
 from repro.launch.hloparse import analyze as hlo_analyze  # noqa: E402
 from repro.launch.mesh import make_mesh_plan, make_production_mesh  # noqa: E402
@@ -172,7 +171,7 @@ def run_cell(arch: str, shape_id: str, multi_pod: bool,
                ("argument_size_in_bytes", "output_size_in_bytes",
                 "temp_size_in_bytes", "generated_code_size_in_bytes",
                 "alias_size_in_bytes") if hasattr(mem, attr)}
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     cost_rec = {k: float(v) for k, v in cost.items()
                 if isinstance(v, (int, float)) and k in
                 ("flops", "transcendentals", "bytes accessed")}

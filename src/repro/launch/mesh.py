@@ -13,11 +13,21 @@ import jax
 from repro.models.sharding import MeshPlan, MULTI_POD, SINGLE_POD
 
 
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    The model code steers layouts with ``with_sharding_constraint``,
+    which only asserts (and fails) on the explicit axes ``make_mesh``
+    builds by default."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_mesh_plan(*, multi_pod: bool = False,
@@ -34,10 +44,3 @@ def make_mesh_plan(*, multi_pod: bool = False,
     return MeshPlan(batch=base.batch, sp=sequence_parallel, fsdp=fsdp_axes,
                     moe_ws=moe_ws)
 
-
-def make_debug_mesh(data: int = 2, model: int = 2, pod: int | None = None):
-    """Small host-device mesh for CPU multi-device tests (requires
-    XLA_FLAGS=--xla_force_host_platform_device_count set in a subprocess)."""
-    if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
-
 from repro.configs.base import ModelConfig
 from .layers import dense_init, swiglu, swiglu_init
 
@@ -247,7 +245,7 @@ def moe_ffn_ep(p, x, cfg: ModelConfig, ep_axis: str,
     xf = x.reshape(-1, d)
     T = xf.shape[0]
     m = cfg.moe
-    ep = axis_size(ep_axis)
+    ep = lax.axis_size(ep_axis)
     E_loc = m.n_experts // ep
     if E_loc * ep != m.n_experts:
         raise ValueError(f"{m.n_experts} experts not divisible by EP={ep}")

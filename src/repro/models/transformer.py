@@ -13,8 +13,6 @@ from dataclasses import dataclass
 from typing import Any, Optional
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -204,7 +202,7 @@ class DecoderLM:
             aux = lax.pmean(aux, plan.batch_axes)
             return y, aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(p_specs, x_spec),
             out_specs=(x_spec, P()),
@@ -233,7 +231,7 @@ class DecoderLM:
                                     fsdp_axis=plan.fsdp)
             return y, lax.pmean(aux, plan.batch_axes)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh, in_specs=(p_specs, x_spec),
             out_specs=(x_spec, P()), check_vma=False)(p, x)
 

@@ -452,8 +452,8 @@ _JIT_SEEN: set = set()
 def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
                             net: NetParams, use_pallas: bool,
                             recorder=None) -> FlowSimResult:
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from .fairshare import _compress_edges
 
@@ -477,7 +477,7 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
     cold = key not in _JIT_SEEN
     _JIT_SEEN.add(key)
     t0_wall = time.perf_counter()
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _event_loop_jit()(
             jnp.asarray(inc.flow), jnp.asarray(edge_c),
             jnp.asarray(inc.frac), jnp.asarray(cap_c),

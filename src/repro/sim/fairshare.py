@@ -18,14 +18,15 @@ flows freeze.  Three solver paths compute the identical fixpoint:
             (``tests/golden/fairshare_golden.json``).
 ``jax``     the whole solve as ONE jitted ``lax.while_loop`` over sparse
             COO segment ops (``jax.ops.segment_sum``) — no Python
-            round-trip per round, float64 via a ``jax.experimental
-            .enable_x64`` scope regardless of the global flag.  This is
-            the 65K-NIC path (``results/BENCH_sim_scale.json``).
+            round-trip per round, float64 via a ``jax.enable_x64(True)``
+            scope regardless of the global flag.  This is the 65K-NIC
+            path, and the one that runs on a TPU.
 ``pallas``  the same while_loop with the segment reductions lowered to
-            the Pallas one-hot contraction kernels
-            (:mod:`repro.kernels.segment_fairshare`), interpreter-mode
-            on CPU (the ref fallback), compiled on real TPUs.
-``auto``    jax when 64-bit mode is on (the
+            the Pallas kernels (:mod:`repro.kernels.segment_fairshare`),
+            run by the Pallas interpreter on the CPU.  A TPU refuses it:
+            Mosaic compiles the kernels at float32 only, and the 1e-9
+            agreement below needs float64.
+``auto``    jax on a TPU; elsewhere jax when 64-bit mode is on (the
             :func:`~repro.core.routing_vec.get_backend` contract),
             numpy otherwise.
 
@@ -135,13 +136,27 @@ def flow_incidence(router, demands: DemandArrays,
 
 
 def resolve_sim_backend(backend: str = "numpy") -> str:
-    """Normalize a fair-share solver backend name (``auto`` follows the
-    router engines' :func:`get_backend` contract: jax only under x64)."""
+    """Normalize a fair-share solver backend name for this platform.
+
+    ``auto`` is jax on a TPU and otherwise follows the router engines'
+    :func:`get_backend` contract (jax only under x64); ``pallas`` is
+    refused on a TPU (see the module docstring)."""
     if backend not in FAIRSHARE_BACKENDS:
         raise ValueError(f"unknown fairshare backend {backend!r}; "
                          f"expected one of {FAIRSHARE_BACKENDS}")
+    if backend not in ("auto", "pallas"):
+        return backend
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
+    if backend == "pallas" and on_tpu:
+        raise ValueError(
+            "the pallas fair-share backend cannot run on a TPU: Mosaic "
+            "compiles its segment kernels at float32 only, and the solver "
+            "needs float64 for its 1e-9 agreement with the numpy "
+            "reference; use the jax backend")
     if backend == "auto":
-        return get_backend("auto")[0]       # "jax" under x64, else "numpy"
+        return "jax" if on_tpu else get_backend("auto")[0]
     return backend
 
 
@@ -177,11 +192,11 @@ def max_min_rates(inc: FlowIncidence, rate_caps_gbps: np.ndarray,
     if backend == "numpy":
         return _max_min_rates_reference(inc, caps, active)
     tol = 1e-12 * _waterfill_scale(inc, caps)
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     used, edge_c, cap_c = _compress_edges(inc)
-    with enable_x64():
+    with jax.enable_x64(True):
         rates, converged, rounds = _waterfill_jit()(
             jnp.asarray(inc.flow), jnp.asarray(edge_c),
             jnp.asarray(inc.frac), jnp.asarray(cap_c),
@@ -268,13 +283,15 @@ def _compress_edges(inc: FlowIncidence):
 
 
 def _segment_sum(vals, ids, n_segments: int, use_pallas: bool):
-    """Backend-selected COO scatter-add (traced inside jit)."""
+    """Backend-selected COO scatter-add (traced inside jit).  The Pallas
+    kernel is interpreted on the CPU and compiled everywhere else."""
+    import jax
+
     if use_pallas:
         from repro.kernels.segment_fairshare import segment_sum
 
-        return segment_sum(vals, ids, n_segments)
-    import jax
-
+        return segment_sum(vals, ids, n_segments,
+                           interpret=jax.default_backend() == "cpu")
     return jax.ops.segment_sum(vals, ids, num_segments=n_segments)
 
 

@@ -8,11 +8,12 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
-from repro.compat import cost_analysis, set_mesh, shard_map
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax import shard_map  # noqa: E402
+from jax.sharding import PartitionSpec as P, set_mesh  # noqa: E402
 
+from repro.launch.mesh import make_auto_mesh  # noqa: E402
 from repro.core.collectives import (decomposed_psum, hierarchical_psum,  # noqa: E402
                                     int8_psum, multiplane_all_gather,
                                     multiplane_psum, psum_auto)
@@ -26,7 +27,7 @@ def check(name, ok, detail=""):
 
 
 def mesh2d(data=4, model=2):
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_auto_mesh((data, model), ("data", "model"))
 
 
 def test_collectives_match_psum():
@@ -187,7 +188,7 @@ def test_sharded_trainer_matches_unsharded():
     with tempfile.TemporaryDirectory() as d:
         ck = Checkpointer(d)
         ck.save(1, s1b)
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = make_auto_mesh((2, 2), ("data", "model"))
         model2 = DecoderLM(cfg, run, mesh=mesh2, plan=MeshPlan())
         t2 = Trainer(model2, run, mesh=mesh2, plan=MeshPlan())
         template = jax.eval_shape(lambda: t2.init_state(jax.random.PRNGKey(0)))
@@ -211,7 +212,7 @@ def test_mini_dryrun_multipod():
 
     cfg = ModelConfig(arch_id="t", family="dense", n_layers=2, d_model=32,
                       n_heads=4, n_kv_heads=2, d_ff=64, vocab_size=128)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
     plan = MULTI_POD
     run = RunConfig()
     model = DecoderLM(cfg, run, mesh=mesh, plan=plan)
@@ -224,7 +225,7 @@ def test_mini_dryrun_multipod():
     lowered = step.lower(state_shapes, batch)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     check("mini_dryrun_compiles", True,
           f"flops={cost.get('flops', 0):.2e}")
     # collectives exist only POST-partitioning: parse compiled HLO, not the

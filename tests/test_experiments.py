@@ -9,6 +9,7 @@ markdown artifacts.
 
 import json
 import os
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -279,6 +280,29 @@ def test_cli_main(tmp_path):
                "--modes", "minimal", "--loads", "1.0"])
     assert rc == 0
     assert (tmp_path / "sweep.json").exists()
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    import jax
+    from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # placed from outside: JAX reads the variable itself, and the
+        # helper sets no other directory
+        placed = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        jax.config.update("jax_compilation_cache_dir", placed)
+        assert enable_compile_cache() == placed
+        assert jax.config.jax_compilation_cache_dir == placed
+        # otherwise: a fixed .jax_cache at the checkout root
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert CHECKOUT_CACHE_DIR == Path(root) / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_markdown_table_formatting():

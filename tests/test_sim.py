@@ -165,9 +165,7 @@ def test_max_min_fractional_incidence():
 
 
 def test_max_min_jax_backend_matches_numpy():
-    jax = pytest.importorskip("jax")
-    if not jax.config.jax_enable_x64:
-        pytest.skip("jax without x64: float32 accumulators")
+    # the jax solver runs in its own float64 scope, whatever the global flag
     router = make_router(MPHX_SMALL, backend="numpy")
     dem = neighbor_shift_demands(MPHX_SMALL, 1600.0)
     inc = flow_incidence(router, dem, "minimal")
@@ -175,6 +173,33 @@ def test_max_min_jax_backend_matches_numpy():
     r_np = max_min_rates(inc, caps, backend="numpy")
     r_jx = max_min_rates(inc, caps, backend="jax")
     assert np.abs(r_np - r_jx).max() < 1e-9
+
+
+def test_sim_backend_follows_the_platform(monkeypatch):
+    import jax
+    from repro.sim.fairshare import resolve_sim_backend
+
+    # CPU: auto keeps the router engines' contract, pallas interprets
+    assert resolve_sim_backend("auto") == (
+        "jax" if jax.config.jax_enable_x64 else "numpy")
+    assert resolve_sim_backend("pallas") == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_sim_backend("auto") == "jax"
+    assert resolve_sim_backend("numpy") == "numpy"
+    with pytest.raises(ValueError, match="float64"):
+        resolve_sim_backend("pallas")
+
+
+def test_cli_refuses_pallas_on_a_tpu(monkeypatch, tmp_path, capsys):
+    import jax
+    from repro.experiments.run import main
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rc = main(["--suite", "sim", "--sim-backend", "pallas",
+               "--out", str(tmp_path)])
+    assert rc == 2
+    assert "Mosaic" in capsys.readouterr().err
+    assert not (tmp_path / "sim.json").exists()
 
 
 # ------------------------------------------------------------- event loop ----
