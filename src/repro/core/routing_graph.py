@@ -54,6 +54,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..telemetry import span
 from .routing_vec import (BaseLinkLoads, DemandArrays, IncidenceCacheMixin,
                           backend_zeros, get_backend)
 from .topology import SwitchGraph, Topology
@@ -245,50 +246,57 @@ class GraphRouter(IncidenceCacheMixin):
         ``demands``; self-pairs (src == dst) get no entries.  Only
         ``minimal`` has a static per-flow spread here — ``valiant``
         averages over every intermediate switch and ``adaptive`` re-routes
-        under load.
+        under load.  Its two phases are the spans ``incidence.walk`` (the
+        per-pair walks) and ``incidence.coalesce`` (joining the pairs'
+        entries into the COO arrays).
         """
         if mode != "minimal":
             raise ValueError(
                 f"no static per-flow incidence for graph-engine mode "
                 f"{mode!r} (valiant averages over all intermediates, "
                 "adaptive re-routes under load); use minimal")
-        self._count_walk()
-        src = np.asarray(demands.src, dtype=np.int64)
-        dst = np.asarray(demands.dst, dtype=np.int64)
-        keep = np.flatnonzero(src != dst)
-        pairs = np.stack([src[keep], dst[keep]], axis=1)
-        upairs, pair_of = np.unique(pairs, axis=0, return_inverse=True)
-        # flows grouped by pair: flows_sorted[pair_start[p]:pair_start[p+1]]
-        # are the flow rows sharing unique pair p
-        order = np.argsort(pair_of, kind="stable")
-        flows_sorted = keep[order]
-        pair_start = np.searchsorted(pair_of[order],
-                                     np.arange(upairs.shape[0] + 1))
-        S = self.csr.n_switches
-        chunk = min(self.dst_chunk, 256)
-        flows, edges, fracs = [], [], []
-        for lo in range(0, upairs.shape[0], chunk):
-            cols = np.arange(lo, min(lo + chunk, upairs.shape[0]))
-            inject = np.zeros((S, cols.shape[0]))
-            inject[upairs[cols, 0], np.arange(cols.shape[0])] = 1.0
-            out = self._incidence_to_dests(upairs[cols, 1], inject)
-            # transposed nonzero scan -> entries arrive grouped by column
-            c_idx, e_idx = np.nonzero(out.T)
-            vals = out.T[c_idx, e_idx]
-            # replicate each column's entry block once per flow of its pair
-            n_ent = np.bincount(c_idx, minlength=cols.shape[0])
-            ent_start = np.concatenate(([0], np.cumsum(n_ent)))
-            for ci, p in enumerate(cols):
-                ent = slice(ent_start[ci], ent_start[ci + 1])
-                for f in flows_sorted[pair_start[p]:pair_start[p + 1]]:
-                    flows.append(np.full(int(n_ent[ci]), f, dtype=np.int64))
-                    edges.append(e_idx[ent])
-                    fracs.append(vals[ent])
-        if not flows:
-            z = np.zeros(0, dtype=np.int64)
-            return z, z.copy(), np.zeros(0)
-        return (np.concatenate(flows), np.concatenate(edges),
-                np.concatenate(fracs))
+        with span("incidence.walk"):
+            self._count_walk()
+            src = np.asarray(demands.src, dtype=np.int64)
+            dst = np.asarray(demands.dst, dtype=np.int64)
+            keep = np.flatnonzero(src != dst)
+            pairs = np.stack([src[keep], dst[keep]], axis=1)
+            upairs, pair_of = np.unique(pairs, axis=0, return_inverse=True)
+            # flows grouped by pair: flows_sorted[pair_start[p]:
+            # pair_start[p+1]] are the flow rows sharing unique pair p
+            order = np.argsort(pair_of, kind="stable")
+            flows_sorted = keep[order]
+            pair_start = np.searchsorted(pair_of[order],
+                                         np.arange(upairs.shape[0] + 1))
+            S = self.csr.n_switches
+            chunk = min(self.dst_chunk, 256)
+            flows, edges, fracs = [], [], []
+            for lo in range(0, upairs.shape[0], chunk):
+                cols = np.arange(lo, min(lo + chunk, upairs.shape[0]))
+                inject = np.zeros((S, cols.shape[0]))
+                inject[upairs[cols, 0], np.arange(cols.shape[0])] = 1.0
+                out = self._incidence_to_dests(upairs[cols, 1], inject)
+                # transposed nonzero scan -> entries arrive grouped by
+                # column
+                c_idx, e_idx = np.nonzero(out.T)
+                vals = out.T[c_idx, e_idx]
+                # replicate each column's entry block once per flow of its
+                # pair
+                n_ent = np.bincount(c_idx, minlength=cols.shape[0])
+                ent_start = np.concatenate(([0], np.cumsum(n_ent)))
+                for ci, p in enumerate(cols):
+                    ent = slice(ent_start[ci], ent_start[ci + 1])
+                    for f in flows_sorted[pair_start[p]:pair_start[p + 1]]:
+                        flows.append(np.full(int(n_ent[ci]), f,
+                                             dtype=np.int64))
+                        edges.append(e_idx[ent])
+                        fracs.append(vals[ent])
+        with span("incidence.coalesce"):
+            if not flows:
+                z = np.zeros(0, dtype=np.int64)
+                return z, z.copy(), np.zeros(0)
+            return (np.concatenate(flows), np.concatenate(edges),
+                    np.concatenate(fracs))
 
     def mean_switch_hops(self) -> float:
         """Measured mean switch-switch hops over NIC-weighted switch pairs

@@ -35,6 +35,7 @@ from typing import Sequence
 
 import numpy as np
 
+from ..telemetry import span
 from .hyperx import MPHX
 
 Edge = tuple[int, int]
@@ -377,8 +378,7 @@ class IncidenceCacheMixin:
     ``incidence.cache_hits`` / ``incidence.cache_misses`` count pairs
     served from / added to the cache.  When an ambient registry is
     collecting (:func:`repro.telemetry.collecting`), the same events are
-    mirrored there.  ``incidence_calls`` remains as a deprecated alias of
-    the walk counter.  Invalidate with :meth:`reset_incidence_cache`
+    mirrored there.  Invalidate with :meth:`reset_incidence_cache`
     after anything that changes routes (e.g. failure masking builds a new
     router, which starts cold anyway).
     """
@@ -395,20 +395,6 @@ class IncidenceCacheMixin:
     @metrics.setter
     def metrics(self, registry) -> None:
         self._metrics = registry
-
-    @property
-    def incidence_calls(self) -> int:
-        """Deprecated alias of ``metrics.value("incidence.walks")``."""
-        return int(self.metrics.value("incidence.walks"))
-
-    @incidence_calls.setter
-    def incidence_calls(self, value: int) -> None:
-        import warnings
-        warnings.warn(
-            "incidence_calls is deprecated; use "
-            "router.metrics.value('incidence.walks')",
-            DeprecationWarning, stacklevel=2)
-        self.metrics.set_counter("incidence.walks", int(value))
 
     def _count_walk(self) -> None:
         from ..telemetry import get_metrics
@@ -594,51 +580,59 @@ class VectorizedHyperXRouter(IncidenceCacheMixin):
         indexes rows of ``demands``.  Supported modes are the fixed path
         spreads: ``minimal`` (ordering ECMP) and ``valiant`` (DAL
         deroutes); ``adaptive`` re-routes under load and has no static
-        incidence.
+        incidence.  Its two phases are the spans ``incidence.walk`` (the
+        hop walks) and ``incidence.coalesce`` (merging duplicate
+        (flow, slot) entries).
         """
-        self._count_walk()
-        src, dst, gbps, cs, cd = self._prep(demands)
-        n_full = math.factorial(self.index.D)
-        flows, slots_l, fracs = [], [], []
+        with span("incidence.walk"):
+            self._count_walk()
+            src, dst, gbps, cs, cd = self._prep(demands)
+            n_full = math.factorial(self.index.D)
+            flows, slots_l, fracs = [], [], []
 
-        def emit(slots, mask, w):
-            f = np.flatnonzero(mask)
-            if f.size:
-                flows.append(f)
-                slots_l.append(slots[mask])
-                fracs.append(w[mask] if w.ndim else np.full(f.size, w))
+            def emit(slots, mask, w):
+                f = np.flatnonzero(mask)
+                if f.size:
+                    flows.append(f)
+                    slots_l.append(slots[mask])
+                    fracs.append(w[mask] if w.ndim else np.full(f.size, w))
 
-        if mode == "minimal":
-            w = np.float64(1.0 / n_full)
-            for slots, mask in self._iter_minimal_hops(src, cs, cd):
-                emit(slots, mask, w)
-        elif mode == "valiant":
-            if np.any(src == dst):
-                raise ValueError("valiant routing expects src != dst demands")
-            mism, m, n_minimal, n_deroute = self._mismatch_stats(cs, cd)
-            n_paths = (n_minimal + n_deroute).astype(np.float64)
-            w_min = n_minimal / (n_paths * n_full)
-            w_der = 1.0 / n_paths
-            for slots, mask in self._iter_minimal_hops(src, cs, cd):
-                emit(slots, mask, w_min)
-            for slots, mask in self._iter_deroute_hops(src, cs, cd, mism):
-                emit(slots, mask, w_der)
-        else:
-            raise ValueError(
-                f"no static per-flow incidence for mode {mode!r} "
-                "(adaptive re-routes under load); use minimal or valiant")
-        if not flows:
-            z = np.zeros(0, dtype=np.int64)
-            return z, z.copy(), np.zeros(0)
-        flow = np.concatenate(flows)
-        slot = np.concatenate(slots_l)
-        frac = np.concatenate(fracs)
-        # coalesce duplicate (flow, slot) entries
-        key = flow * np.int64(self.index.n_slots) + slot
-        uniq, inv = np.unique(key, return_inverse=True)
-        out = np.zeros(uniq.size)
-        np.add.at(out, inv, frac)
-        return (uniq // self.index.n_slots, uniq % self.index.n_slots, out)
+            if mode == "minimal":
+                w = np.float64(1.0 / n_full)
+                for slots, mask in self._iter_minimal_hops(src, cs, cd):
+                    emit(slots, mask, w)
+            elif mode == "valiant":
+                if np.any(src == dst):
+                    raise ValueError(
+                        "valiant routing expects src != dst demands")
+                mism, m, n_minimal, n_deroute = self._mismatch_stats(cs, cd)
+                n_paths = (n_minimal + n_deroute).astype(np.float64)
+                w_min = n_minimal / (n_paths * n_full)
+                w_der = 1.0 / n_paths
+                for slots, mask in self._iter_minimal_hops(src, cs, cd):
+                    emit(slots, mask, w_min)
+                for slots, mask in self._iter_deroute_hops(src, cs, cd,
+                                                           mism):
+                    emit(slots, mask, w_der)
+            else:
+                raise ValueError(
+                    f"no static per-flow incidence for mode {mode!r} "
+                    "(adaptive re-routes under load); use minimal or "
+                    "valiant")
+        with span("incidence.coalesce"):
+            if not flows:
+                z = np.zeros(0, dtype=np.int64)
+                return z, z.copy(), np.zeros(0)
+            flow = np.concatenate(flows)
+            slot = np.concatenate(slots_l)
+            frac = np.concatenate(fracs)
+            # coalesce duplicate (flow, slot) entries
+            key = flow * np.int64(self.index.n_slots) + slot
+            uniq, inv = np.unique(key, return_inverse=True)
+            out = np.zeros(uniq.size)
+            np.add.at(out, inv, frac)
+            return (uniq // self.index.n_slots, uniq % self.index.n_slots,
+                    out)
 
     def mean_switch_hops(self) -> float:
         """Expected switch-switch minimal hops over uniform NIC pairs

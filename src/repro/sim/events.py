@@ -20,14 +20,14 @@ uncontended flow's FCT is exactly the closed-form
 from __future__ import annotations
 
 import functools
-import time
+import itertools
 from dataclasses import dataclass
 
 import numpy as np
 
 from repro.core.netsim import DEFAULT_NET, NetParams, gbps_to_Bps
 from repro.core.routing_vec import DemandArrays
-from repro.telemetry import get_metrics, get_recorder
+from repro.telemetry import get_metrics, get_recorder, span
 from .fairshare import (FlowIncidence, _segment_sum, _waterfill_body,
                         _waterfill_scale, flow_incidence, max_min_rates,
                         resolve_sim_backend)
@@ -167,6 +167,11 @@ def _normalize_tags(tags, F: int) -> "np.ndarray | None":
     return out
 
 
+# ordinal of each simulation in the process: the ``run`` argument of its
+# ``sim.simulate`` span, which groups a trace's spans by simulation
+_RUN_ORDINAL = itertools.count()
+
+
 def simulate_incidence(inc: FlowIncidence, size_bytes, rate_caps_gbps,
                        start_s=None, net: NetParams = DEFAULT_NET,
                        backend: str = "numpy", tags=None) -> FlowSimResult:
@@ -189,40 +194,44 @@ def simulate_incidence(inc: FlowIncidence, size_bytes, rate_caps_gbps,
     active-flow count, utilization of the recorder's selected link subset
     — with identical row count and ordering, plus per-flow transfer
     spans.  With no recorder the numpy loop skips the journal code
-    entirely and the jitted loop compiles the exact pre-telemetry graph
+    entirely and the jitted loop leaves the journal out of its graph
     (``record`` is a static argument), so disabled telemetry cannot
     perturb the golden float sequences.
+
+    The call is the span ``sim.simulate`` (:func:`repro.telemetry.span`,
+    with the process's simulation ordinal as its ``run`` argument); on
+    the jit path its phases are the child spans ``sim.compress``,
+    ``sim.transfer``, ``sim.loop``, ``sim.readback`` and ``sim.finalize``.
     """
-    F = inc.n_flows
-    size = np.broadcast_to(np.asarray(size_bytes, dtype=np.float64),
-                           (F,)).copy()
-    caps = np.broadcast_to(np.asarray(rate_caps_gbps, dtype=np.float64),
-                           (F,)).copy()
-    start = (np.zeros(F) if start_s is None else
-             np.broadcast_to(np.asarray(start_s, dtype=np.float64),
-                             (F,)).copy())
-    if np.any(size < 0) or np.any(caps <= 0):
-        raise ValueError("sizes must be >= 0 and rate caps > 0")
-    backend = resolve_sim_backend(backend)
-    tag_arr = _normalize_tags(tags, F)
-    rec = get_recorder()
-    mx = get_metrics()
-    t0_wall = time.perf_counter()
-    if backend != "numpy" and F > 0:
-        res = _simulate_incidence_jit(inc, size, caps, start, net,
-                                      use_pallas=(backend == "pallas"),
-                                      recorder=rec)
-    else:
-        res = _simulate_incidence_numpy(inc, size, caps, start, net,
-                                        backend, recorder=rec)
-    res.tags = tag_arr
-    mx.inc("sim.runs")
-    mx.inc("sim.flows", F)
-    mx.inc("sim.epochs", res.n_epochs)
-    mx.observe("sim.wall_s", time.perf_counter() - t0_wall)
-    if rec is not None:
-        rec.record_flow_sim(res)
-    return res
+    with span("sim.simulate", run=next(_RUN_ORDINAL)):
+        F = inc.n_flows
+        size = np.broadcast_to(np.asarray(size_bytes, dtype=np.float64),
+                               (F,)).copy()
+        caps = np.broadcast_to(
+            np.asarray(rate_caps_gbps, dtype=np.float64), (F,)).copy()
+        start = (np.zeros(F) if start_s is None else
+                 np.broadcast_to(np.asarray(start_s, dtype=np.float64),
+                                 (F,)).copy())
+        if np.any(size < 0) or np.any(caps <= 0):
+            raise ValueError("sizes must be >= 0 and rate caps > 0")
+        backend = resolve_sim_backend(backend)
+        tag_arr = _normalize_tags(tags, F)
+        rec = get_recorder()
+        mx = get_metrics()
+        if backend != "numpy" and F > 0:
+            res = _simulate_incidence_jit(inc, size, caps, start, net,
+                                          use_pallas=(backend == "pallas"),
+                                          recorder=rec)
+        else:
+            res = _simulate_incidence_numpy(inc, size, caps, start, net,
+                                            backend, recorder=rec)
+        res.tags = tag_arr
+        mx.inc("sim.runs")
+        mx.inc("sim.flows", F)
+        mx.inc("sim.epochs", res.n_epochs)
+        if rec is not None:
+            rec.record_flow_sim(res)
+        return res
 
 
 def _simulate_incidence_numpy(inc: FlowIncidence, size, caps, start,
@@ -302,15 +311,16 @@ def _simulate_incidence_numpy(inc: FlowIncidence, size, caps, start,
 def _finalize_result(inc: FlowIncidence, size, caps, start, finish,
                      edge_bytes, n_epochs: int, net: NetParams
                      ) -> FlowSimResult:
-    lat = path_latency(inc, net)
-    fct = finish - start + lat
-    done = np.isfinite(finish)
-    return FlowSimResult(
-        start_s=start, finish_s=finish, fct_s=fct, latency_s=lat,
-        size_bytes=size, edge_bytes=edge_bytes, incidence=inc,
-        makespan_s=float((finish[done] - start.min()).max())
-        if done.any() else 0.0,
-        n_epochs=n_epochs)
+    with span("sim.finalize"):
+        lat = path_latency(inc, net)
+        fct = finish - start + lat
+        done = np.isfinite(finish)
+        return FlowSimResult(
+            start_s=start, finish_s=finish, fct_s=fct, latency_s=lat,
+            size_bytes=size, edge_bytes=edge_bytes, incidence=inc,
+            makespan_s=float((finish[done] - start.min()).max())
+            if done.any() else 0.0,
+            n_epochs=n_epochs)
 
 
 @functools.lru_cache(maxsize=1)
@@ -324,13 +334,23 @@ def _event_loop_jit():
     the next start/finish event.  Same constants, same branch structure,
     same freeze tolerances — the golden fixtures hold it to 1e-9.
 
+    The loop state also counts the water-filling rounds of every epoch's
+    solve (``rounds``, int32, numerically inert): the ``waterfill.rounds``
+    counter of the jit path.  Named scopes (op metadata only) mark the
+    device work: ``waterfill.edge_load``, ``waterfill.step`` and
+    ``waterfill.freeze`` inside a round, ``epoch.admit``,
+    ``epoch.advance``, ``epoch.edge_bytes`` and ``epoch.journal`` in an
+    epoch.
+
     ``record`` (static) threads the flight-recorder epoch journal —
     per-epoch clock/dt/active-count plus utilization of the ``sel``
     compressed-edge subset, written into fixed ``max_j``-row arrays with
     masked writes (rows past ``max_j`` are counted, not written, matching
     the reference loop's journal cap).  With ``record=False`` the journal
-    keys never enter the loop state, so the compiled graph is exactly the
-    pre-telemetry one.
+    keys never enter the loop state.
+
+    Returns ``(finish, edge_bytes, n_epochs, done, ok)``, then the
+    journal's ``(t, dt, active, util)`` when ``record``, then ``rounds``.
     """
     import jax
     import jax.numpy as jnp
@@ -348,74 +368,83 @@ def _event_loop_jit():
             flow, edge, frac, cap_e, caps, tol, E, use_pallas)
 
         def solve(active):
-            rates, unfrozen, _, _ = jax.lax.while_loop(
+            rates, unfrozen, _, rounds = jax.lax.while_loop(
                 wf_cond, wf_body, wf_init(active))
-            return rates, jnp.logical_not(unfrozen.any())
+            return rates, jnp.logical_not(unfrozen.any()), rounds
 
         def cond(s):
             return jnp.logical_and(~s["done"], s["i"] < 4 * F + 8)
 
         def body(s):
             t = s["t"]
-            open_f = (s["remaining"] > thresh) & ~s["stalled"]
-            active = open_f & (start <= t * (1 + 1e-12) + 1e-18)
-            pend = open_f & ~active
-            has_pending = pend.any()
-            pending_min = jnp.where(pend, start, jnp.inf).min()
+            with jax.named_scope("epoch.admit"):
+                open_f = (s["remaining"] > thresh) & ~s["stalled"]
+                active = open_f & (start <= t * (1 + 1e-12) + 1e-18)
+                pend = open_f & ~active
+                has_pending = pend.any()
+                pending_min = jnp.where(pend, start, jnp.inf).min()
+                any_active = active.any()
 
             def no_active(s):
                 # break if nothing is pending, else jump to next arrival
-                return dict(s, t=jnp.where(has_pending, pending_min, t),
-                            done=s["done"] | ~has_pending)
+                with jax.named_scope("epoch.advance"):
+                    return dict(s, t=jnp.where(has_pending, pending_min, t),
+                                done=s["done"] | ~has_pending)
 
             def with_active(s):
-                rates, conv = solve(active)
-                rates = jnp.where(active, rates, 0.0)
-                dead = active & (rates <= 0)
-                do_stall = dead.any() & ~has_pending
-                stall_set = dead & do_stall
-                act = active & ~stall_set
-                proceed = act.any()
-                Bps = rates * (1e9 / 8.0)
-                per_dt = jnp.where(
-                    act, s["remaining"] / jnp.maximum(Bps, 1e-30),
-                    jnp.inf)
-                dt_arr = jnp.where(has_pending, pending_min - t, jnp.inf)
-                dt = jnp.where(proceed,
-                               jnp.minimum(per_dt.min(), dt_arr), 0.0)
-                # dt=0 when everything active just stalled — the
-                # reference loop's stall-continue epoch
-                moved = Bps * dt
-                remaining = jnp.maximum(s["remaining"] - moved, 0.0)
-                t2 = t + dt
-                just_done = act & (remaining <= thresh)
+                rates, conv, rounds = solve(active)
+                with jax.named_scope("epoch.advance"):
+                    rates = jnp.where(active, rates, 0.0)
+                    dead = active & (rates <= 0)
+                    do_stall = dead.any() & ~has_pending
+                    stall_set = dead & do_stall
+                    act = active & ~stall_set
+                    proceed = act.any()
+                    Bps = rates * (1e9 / 8.0)
+                    per_dt = jnp.where(
+                        act, s["remaining"] / jnp.maximum(Bps, 1e-30),
+                        jnp.inf)
+                    dt_arr = jnp.where(has_pending, pending_min - t,
+                                       jnp.inf)
+                    dt = jnp.where(proceed,
+                                   jnp.minimum(per_dt.min(), dt_arr), 0.0)
+                    # dt=0 when everything active just stalled — the
+                    # reference loop's stall-continue epoch
+                    moved = Bps * dt
+                    remaining = jnp.maximum(s["remaining"] - moved, 0.0)
+                    t2 = t + dt
+                    just_done = act & (remaining <= thresh)
+                with jax.named_scope("epoch.edge_bytes"):
+                    edge_bytes = s["edge_bytes"] + _segment_sum(
+                        moved[flow] * frac, edge, E, use_pallas)
                 s2 = dict(
                     s, t=t2, remaining=remaining,
                     finish=jnp.where(just_done, t2, s["finish"]),
                     stalled=s["stalled"] | stall_set,
-                    edge_bytes=s["edge_bytes"] + _segment_sum(
-                        moved[flow] * frac, edge, E, use_pallas),
-                    n_epochs=s["n_epochs"] + 1, ok=s["ok"] & conv)
+                    edge_bytes=edge_bytes,
+                    n_epochs=s["n_epochs"] + 1, ok=s["ok"] & conv,
+                    rounds=s["rounds"] + rounds)
                 if record:
-                    idx = jnp.minimum(s["n_epochs"], max_j - 1)
-                    okr = s["n_epochs"] < max_j
-                    loads = _segment_sum(
-                        jnp.where(act, rates, 0.0)[flow] * frac, edge,
-                        E, use_pallas)
-                    util = jnp.where(cap_e[sel] > 0,
-                                     loads[sel] / cap_e[sel], 0.0)
-                    s2["j_t"] = s["j_t"].at[idx].set(
-                        jnp.where(okr, t, s["j_t"][idx]))
-                    s2["j_dt"] = s["j_dt"].at[idx].set(
-                        jnp.where(okr, dt, s["j_dt"][idx]))
-                    s2["j_act"] = s["j_act"].at[idx].set(
-                        jnp.where(okr, act.sum().astype(jnp.int32),
-                                  s["j_act"][idx]))
-                    s2["j_util"] = s["j_util"].at[idx].set(
-                        jnp.where(okr, util, s["j_util"][idx]))
+                    with jax.named_scope("epoch.journal"):
+                        idx = jnp.minimum(s["n_epochs"], max_j - 1)
+                        okr = s["n_epochs"] < max_j
+                        loads = _segment_sum(
+                            jnp.where(act, rates, 0.0)[flow] * frac, edge,
+                            E, use_pallas)
+                        util = jnp.where(cap_e[sel] > 0,
+                                         loads[sel] / cap_e[sel], 0.0)
+                        s2["j_t"] = s["j_t"].at[idx].set(
+                            jnp.where(okr, t, s["j_t"][idx]))
+                        s2["j_dt"] = s["j_dt"].at[idx].set(
+                            jnp.where(okr, dt, s["j_dt"][idx]))
+                        s2["j_act"] = s["j_act"].at[idx].set(
+                            jnp.where(okr, act.sum().astype(jnp.int32),
+                                      s["j_act"][idx]))
+                        s2["j_util"] = s["j_util"].at[idx].set(
+                            jnp.where(okr, util, s["j_util"][idx]))
                 return s2
 
-            s2 = jax.lax.cond(active.any(), with_active, no_active, s)
+            s2 = jax.lax.cond(any_active, with_active, no_active, s)
             return dict(s2, i=s["i"] + 1)
 
         state = {
@@ -425,6 +454,7 @@ def _event_loop_jit():
             "stalled": jnp.zeros(F, dtype=bool),
             "edge_bytes": jnp.zeros(E, dtype=size.dtype),
             "n_epochs": jnp.int32(0),
+            "rounds": jnp.int32(0),
             "i": jnp.int32(0),
             "done": jnp.bool_(False),
             "ok": jnp.bool_(True),
@@ -439,14 +469,10 @@ def _event_loop_jit():
         base = (out["finish"], out["edge_bytes"], out["n_epochs"],
                 out["done"], out["ok"])
         if record:
-            return base + (out["j_t"], out["j_dt"], out["j_act"],
-                           out["j_util"])
-        return base
+            base += (out["j_t"], out["j_dt"], out["j_act"], out["j_util"])
+        return base + (out["rounds"],)
 
     return run
-
-
-_JIT_SEEN: set = set()
 
 
 def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
@@ -457,10 +483,12 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
 
     from .fairshare import _compress_edges
 
-    tol = 1e-12 * _waterfill_scale(inc, caps)
-    # solve over the used-edge subset (identical float sequence — unused
-    # edges never saturate) and scatter edge_bytes back at the end
-    used, edge_c, cap_c = _compress_edges(inc)
+    with span("sim.compress"):
+        tol = 1e-12 * _waterfill_scale(inc, caps)
+        # solve over the used-edge subset (identical float sequence —
+        # unused edges never saturate) and scatter edge_bytes back at the
+        # end
+        used, edge_c, cap_c = _compress_edges(inc)
     record = recorder is not None and recorder.link_policy is not None
     if record:
         sel_g = recorder.link_policy.select(inc, caps)
@@ -471,42 +499,41 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
         max_j = max(1, recorder.link_policy.max_epochs)
     else:
         sel_c, max_j = None, 0
-    key = (size.shape[0], int(used.size), int(inc.flow.shape[0]),
-           use_pallas, record, max_j,
-           int(sel_c.shape[0]) if record else 0)
-    cold = key not in _JIT_SEEN
-    _JIT_SEEN.add(key)
-    t0_wall = time.perf_counter()
     with jax.enable_x64(True):
-        out = _event_loop_jit()(
-            jnp.asarray(inc.flow), jnp.asarray(edge_c),
-            jnp.asarray(inc.frac), jnp.asarray(cap_c),
-            jnp.asarray(size), jnp.asarray(caps), jnp.asarray(start),
-            jnp.asarray(tol),
-            jnp.asarray(sel_c) if record else None,
-            E=used.size, use_pallas=use_pallas, record=record,
-            max_j=max_j)
-        finish, used_bytes, n_epochs, done, ok = out[:5]
-        if not bool(ok):
-            raise RuntimeError("water-filling failed to converge "
-                               f"({inc.n_flows} flows, {inc.n_edges} "
-                               "edges)")
-        if not bool(done):
-            raise RuntimeError(
-                f"flow sim failed to converge ({inc.n_flows} flows)")
-        finish = np.asarray(finish)
-        edge_bytes = np.zeros(inc.n_edges)
-        edge_bytes[used] = np.asarray(used_bytes)
-        n_epochs = int(n_epochs)
-        if record:
-            j_t, j_dt, j_act, j_util = (np.asarray(a) for a in out[5:9])
-            n = min(n_epochs, max_j)
-            recorder.record_epoch_journal(
-                j_t[:n], j_dt[:n], j_act[:n], sel_g, j_util[:n],
-                dropped=n_epochs - n)
-    get_metrics().observe(
-        "sim.jit_cold_call_s" if cold else "sim.jit_exec_s",
-        time.perf_counter() - t0_wall)
+        with span("sim.transfer"):
+            args = [jnp.asarray(a) for a in (inc.flow, edge_c, inc.frac,
+                                             cap_c, size, caps, start, tol)]
+            args.append(jnp.asarray(sel_c) if record else None)
+        with span("sim.loop"):
+            out = _event_loop_jit()(*args, E=used.size,
+                                    use_pallas=use_pallas, record=record,
+                                    max_j=max_j)
+            finish, used_bytes, n_epochs, done, ok = out[:5]
+            rounds = out[-1]
+            if not bool(ok):
+                raise RuntimeError("water-filling failed to converge "
+                                   f"({inc.n_flows} flows, {inc.n_edges} "
+                                   "edges)")
+            if not bool(done):
+                raise RuntimeError(
+                    f"flow sim failed to converge ({inc.n_flows} flows)")
+        with span("sim.readback"):
+            finish = np.asarray(finish)
+            edge_bytes = np.zeros(inc.n_edges)
+            edge_bytes[used] = np.asarray(used_bytes)
+            n_epochs = int(n_epochs)
+            rounds = int(rounds)
+            if record:
+                j_t, j_dt, j_act, j_util = (np.asarray(a)
+                                            for a in out[5:9])
+                n = min(n_epochs, max_j)
+                recorder.record_epoch_journal(
+                    j_t[:n], j_dt[:n], j_act[:n], sel_g, j_util[:n],
+                    dropped=n_epochs - n)
+    mx = get_metrics()
+    # one water-filling solve per epoch, as in the reference loop
+    mx.inc("waterfill.solves", n_epochs)
+    mx.inc("waterfill.rounds", rounds)
     return _finalize_result(inc, size, caps, start, finish, edge_bytes,
                             n_epochs, net)
 

@@ -298,7 +298,13 @@ def _segment_sum(vals, ids, n_segments: int, use_pallas: bool):
 def _waterfill_body(flow, edge, frac, cap_e, caps, tol, E: int,
                     use_pallas: bool):
     """(cond, body, init-builder) of the water-filling while_loop —
-    shared by the standalone solver and the in-jit event loop."""
+    shared by the standalone solver and the in-jit event loop.  A round's
+    device work carries three named scopes: ``waterfill.edge_load`` (the
+    live entries' segment sum into edges), ``waterfill.step`` (the
+    uniform raise and the edges' remaining capacity) and
+    ``waterfill.freeze`` (saturated edges' segment sum into flows, and
+    the capped flows)."""
+    import jax
     import jax.numpy as jnp
 
     F = caps.shape[0]
@@ -309,22 +315,26 @@ def _waterfill_body(flow, edge, frac, cap_e, caps, tol, E: int,
 
     def body(state):
         rates, unfrozen, cap_left, i = state
-        live = jnp.where(unfrozen[flow], frac, 0.0)
-        wsum = _segment_sum(live, edge, E, use_pallas)
-        open_e = wsum > tol
-        delta_e = jnp.where(open_e,
-                            cap_left / jnp.where(open_e, wsum, 1.0),
-                            jnp.inf)
-        delta_f = jnp.where(unfrozen, caps - rates, jnp.inf)
-        d_edges = delta_e.min() if E else jnp.inf
-        delta = jnp.maximum(jnp.minimum(d_edges, delta_f.min()), 0.0)
-        rates = jnp.where(unfrozen, rates + delta, rates)
-        cap_left = cap_left - delta * wsum
-        sat = open_e & (cap_left <= tol)
-        on_sat = _segment_sum(jnp.where(sat[edge], frac, 0.0), flow, F,
-                              use_pallas) > 0
-        capped = rates >= caps - tol
-        return rates, unfrozen & ~on_sat & ~capped, cap_left, i + 1
+        with jax.named_scope("waterfill.edge_load"):
+            live = jnp.where(unfrozen[flow], frac, 0.0)
+            wsum = _segment_sum(live, edge, E, use_pallas)
+        with jax.named_scope("waterfill.step"):
+            open_e = wsum > tol
+            delta_e = jnp.where(open_e,
+                                cap_left / jnp.where(open_e, wsum, 1.0),
+                                jnp.inf)
+            delta_f = jnp.where(unfrozen, caps - rates, jnp.inf)
+            d_edges = delta_e.min() if E else jnp.inf
+            delta = jnp.maximum(jnp.minimum(d_edges, delta_f.min()), 0.0)
+            rates = jnp.where(unfrozen, rates + delta, rates)
+            cap_left = cap_left - delta * wsum
+        with jax.named_scope("waterfill.freeze"):
+            sat = open_e & (cap_left <= tol)
+            on_sat = _segment_sum(jnp.where(sat[edge], frac, 0.0), flow, F,
+                                  use_pallas) > 0
+            capped = rates >= caps - tol
+            unfrozen = unfrozen & ~on_sat & ~capped
+        return rates, unfrozen, cap_left, i + 1
 
     def init(active):
         return (jnp.zeros(F, dtype=caps.dtype), active, cap_e,

@@ -4,24 +4,23 @@ A :class:`MetricsRegistry` is a plain dict-backed sink for the stack's
 operational metrics: engine walks and incidence-cache hits
 (:mod:`repro.core.routing_vec` / :mod:`repro.core.routing_graph`),
 water-filling round counts and event-loop epochs (:mod:`repro.sim`),
-jit compile-vs-execute wall time, dead-plane re-spray events
-(:mod:`repro.sim.spray`), and re-route recomputes
-(:mod:`repro.sim.failures`).  The catalog lives in
+the phase spans of the solver call and the incidence (:func:`span`),
+dead-plane re-spray events (:mod:`repro.sim.spray`), and re-route
+recomputes (:mod:`repro.sim.failures`).  The catalog lives in
 ``docs/observability.md``.
 
 Two attachment points:
 
 * **per-object** — both routing engines own a registry
-  (``router.metrics``), replacing PR 7's bare ``incidence_calls`` int
-  (kept as a deprecated property shim);
+  (``router.metrics``);
 * **ambient** — :func:`get_metrics` returns the process-wide registry,
   which defaults to the no-op :class:`NullRegistry` singleton.  Code
   instruments unconditionally against the ambient registry; when nothing
-  is collecting, every call hits a ``pass`` body — and the jitted
-  solver/event-loop paths are never instrumented *inside* jit at all, so
-  disabled telemetry cannot perturb the compiled code or the golden
-  float sequences (``tests/test_telemetry.py`` pins this against
-  ``tests/golden/fairshare_golden.json``).
+  is collecting, every call hits a ``pass`` body.  Inside jit the
+  solver/event-loop paths carry only ``jax.named_scope`` names (op
+  metadata) and an integer round counter, so telemetry cannot perturb
+  the golden float sequences (``tests/test_telemetry.py`` pins this
+  against ``tests/golden/fairshare_golden.json``).
 
 Enable collection with :func:`collecting` (or, for traces too,
 :func:`repro.telemetry.trace.recording`)::
@@ -29,6 +28,11 @@ Enable collection with :func:`collecting` (or, for traces too,
     with collecting() as mx:
         simulate_demands(router, dem, 200e-6)
     print(mx.snapshot())
+
+:func:`span` marks a phase of host code twice: as a
+``jax.profiler.TraceAnnotation`` on the profiler's host plane, on the
+same clock as the device's operations, and as a timer of the ambient
+registry.
 """
 
 from __future__ import annotations
@@ -37,7 +41,7 @@ import time
 from contextlib import contextmanager
 
 __all__ = ["MetricsRegistry", "NullRegistry", "NULL_METRICS",
-           "get_metrics", "collecting"]
+           "get_metrics", "collecting", "span"]
 
 
 class MetricsRegistry:
@@ -200,3 +204,31 @@ def collecting(registry: "MetricsRegistry | None" = None):
         yield reg
     finally:
         _ambient = prev
+
+
+class span:
+    """Mark a phase of host code: a ``jax.profiler.TraceAnnotation``
+    named ``name`` (with ``args`` as its arguments), so the phase lands
+    on the profiler's host plane beside the device's operations, and an
+    observation of the ambient registry's timer ``name``, as
+    :meth:`MetricsRegistry.timer` makes.  With the profiler off and
+    nothing collecting it costs one inactive TraceMe and a ``pass``.
+    Spans mark phase boundaries only, never a per-hop or per-epoch
+    Python loop."""
+
+    __slots__ = ("_name", "_ann", "_t0")
+
+    def __init__(self, name: str, **args):
+        from jax.profiler import TraceAnnotation
+
+        self._name = name
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        _ambient.observe(self._name, time.perf_counter() - self._t0)
+        return self._ann.__exit__(*exc)

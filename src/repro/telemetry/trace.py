@@ -37,7 +37,6 @@ Enable with :func:`recording` — it also installs the recorder's
 from __future__ import annotations
 
 import json
-import time
 from contextlib import contextmanager
 from dataclasses import dataclass
 
@@ -110,7 +109,6 @@ class TraceRecorder:
         self._threads: dict = {}
         self._meta: "list[dict]" = []
         self._flow_budget = max_flow_events
-        self._wall0 = time.perf_counter()
 
     # ------------------------------------------------------------ tracks ----
 
@@ -169,20 +167,6 @@ class TraceRecorder:
                             "ts": float(ts_s) * _US, "pid": pid,
                             "args": {k: float(v)
                                      for k, v in values.items()}})
-
-    @contextmanager
-    def wall_span(self, name: str, process: str = "wall",
-                  thread: str = "main", cat: str = "wall",
-                  args: "dict | None" = None):
-        """A span on the host wall clock (relative to recorder start) —
-        for solver/compile wall time, not simulated fabric time."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self.span(name, t0 - self._wall0, t1 - t0, process=process,
-                      thread=thread, cat=cat, args=args)
 
     def note_skip(self, name: str, reason: str) -> None:
         """Explicit record that a suite/bench path produced no trace."""
@@ -252,8 +236,7 @@ class TraceRecorder:
             "displayTimeUnit": "ms",
             "otherData": {
                 "generated_by": "repro.telemetry",
-                "clock": "1 simulated second = 1e6 trace us "
-                         "(wall tracks use host wall clock)",
+                "clock": "1 simulated second = 1e6 trace us",
                 "skipped": self.notes,
                 "metrics": self.metrics.snapshot(),
             },

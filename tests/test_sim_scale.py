@@ -8,7 +8,7 @@ Three layers:
     1e-6 agreement at every rung,
   * the pair-level incidence cache (``IncidenceCacheMixin``): cached
     extraction is byte-identical to the engine walk, repeated flow sets
-    walk the engine exactly once (counted by ``incidence_calls``), and
+    walk the engine exactly once (counted by ``incidence.walks``), and
     the batch simulator rides the cache,
   * a slow-marked smoke that actually routes + simulates a 65,536-NIC
     preset through the jit path and cross-checks numpy at 1e-6.
@@ -94,30 +94,30 @@ def test_cached_incidence_identical_to_engine_walk():
 def test_repeated_flow_sets_walk_engine_once():
     router = _small_router()
     dem = uniform_demands(router.topo, 400.0)
-    assert router.incidence_calls == 0
+    assert router.metrics.value("incidence.walks") == 0
     for _ in range(3):
         flow_incidence(router, dem, "minimal", cached=True)
     # one walk covered all three extractions: every (src, dst) pair was
     # cached on the first pass
-    assert router.incidence_calls == 1
+    assert router.metrics.value("incidence.walks") == 1
     # a new mode is a different path spread: exactly one more walk
     flow_incidence(router, dem, "valiant", cached=True)
     flow_incidence(router, dem, "valiant", cached=True)
-    assert router.incidence_calls == 2
+    assert router.metrics.value("incidence.walks") == 2
     router.reset_incidence_cache()
     flow_incidence(router, dem, "minimal", cached=True)
-    assert router.incidence_calls == 3
+    assert router.metrics.value("incidence.walks") == 3
 
 
 def test_partial_overlap_walks_only_new_pairs():
     router = _small_router()
     a = neighbor_shift_demands(router.topo, 800.0)
     flow_incidence(router, a, "minimal", cached=True)
-    calls = router.incidence_calls
+    calls = router.metrics.value("incidence.walks")
     # a flow set whose pairs are a subset of what's cached: no new walk
     sub = neighbor_shift_demands(router.topo, 800.0)
     flow_incidence(router, sub, "minimal", cached=True)
-    assert router.incidence_calls == calls
+    assert router.metrics.value("incidence.walks") == calls
 
 
 def test_batch_simulator_rides_the_cache():
@@ -128,7 +128,7 @@ def test_batch_simulator_rides_the_cache():
     res = simulate_flow_batches(router, batches, rate_cap_gbps=200.0)
     assert len(res.results) == 4
     # 4 identical phases, 1 engine walk
-    assert router.incidence_calls == 1
+    assert router.metrics.value("incidence.walks") == 1
 
 
 # ----------------------------------------------------- 65K sim smoke ----

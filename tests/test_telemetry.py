@@ -11,8 +11,12 @@ Pins the observability layer's core contracts:
   * the Perfetto ``trace_event`` export round-trips and validates;
   * cosim phase spans tile the step clock — their durations sum to the
     reported communication time (1e-6 relative);
-  * `incidence_calls` survives as a deprecated shim and both routing
-    engines count cache hits/misses uniformly;
+  * ``span`` feeds the ambient registry and the profiler's host plane;
+    the solver call and both engines' incidence report their phase
+    spans once per run, the children inside the parent;
+  * the jit event loop counts the same water-filling rounds as the
+    numpy loop, and its HLO carries every named scope;
+  * both routing engines count walks and cache hits/misses uniformly;
   * ``benchmarks/report.py --check`` passes on the committed BENCH
     history and fails on a synthetic 2x slowdown;
   * a 65K-NIC run's link series stays bounded by ``LinkSeriesPolicy``
@@ -38,7 +42,7 @@ from repro.sim.fairshare import flow_incidence
 from repro.telemetry import (NULL_METRICS, LinkSeriesPolicy,
                              MetricsRegistry, NullRegistry, TraceRecorder,
                              collecting, get_metrics, get_recorder,
-                             recording, validate_trace)
+                             recording, span, validate_trace)
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
@@ -130,19 +134,18 @@ def _engines():
     }
 
 
-def test_incidence_calls_shim_reads_metrics():
-    for name, (router, dem) in _engines().items():
-        assert router.incidence_calls == 0, name
+@pytest.mark.parametrize("engine", ["array", "graph"])
+def test_engine_walks_and_incidence_spans_count_once(engine):
+    router, dem = _engines()[engine]
+    assert router.metrics.value("incidence.walks") == 0
+    with collecting() as mx:
         router.incidence(dem, "minimal")
-        assert router.incidence_calls == 1, name
-        assert router.metrics.value("incidence.walks") == 1, name
-
-
-def test_incidence_calls_setter_warns_deprecation():
-    router, _ = _engines()["array"]
-    with pytest.warns(DeprecationWarning):
-        router.incidence_calls = 0
-    assert router.incidence_calls == 0
+    assert router.metrics.value("incidence.walks") == 1
+    assert mx.value("incidence.walks") == 1
+    timers = mx.snapshot()["timers"]
+    assert {k for k in timers if k.startswith("incidence.")} == \
+        {"incidence.walk", "incidence.coalesce"}
+    assert all(t["count"] == 1 for t in timers.values())
 
 
 def test_cache_hit_miss_uniform_on_both_engines():
@@ -174,7 +177,111 @@ def test_solver_and_sim_counters_flow():
     assert snap["counters"]["waterfill.solves"] >= 1
     assert snap["counters"]["waterfill.rounds"] >= \
         snap["counters"]["waterfill.solves"]
-    assert snap["timers"]["sim.wall_s"]["count"] == 1
+    assert snap["timers"]["sim.simulate"]["count"] == 1
+
+
+SIM_PHASES = ("sim.compress", "sim.transfer", "sim.loop", "sim.readback",
+              "sim.finalize")
+SCOPES = ("waterfill.edge_load", "waterfill.step", "waterfill.freeze",
+          "epoch.admit", "epoch.advance", "epoch.edge_bytes",
+          "epoch.journal")
+
+
+def test_span_feeds_the_registry_and_nests():
+    with span("outer"):            # nothing collecting: a no-op
+        pass
+    with collecting() as mx:
+        with span("outer", run=3):
+            for _ in range(2):
+                with span("inner"):
+                    pass
+        with pytest.raises(KeyError):
+            with span("outer"):
+                raise KeyError("an error still closes the span")
+    timers = mx.snapshot()["timers"]
+    assert timers["outer"]["count"] == 2
+    assert timers["inner"]["count"] == 2
+    assert mx._timers["inner"]["total_s"] <= mx._timers["outer"]["total_s"]
+    assert get_metrics() is NULL_METRICS
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("sim.simulate", run=5):
+            with span("sim.loop"):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("sim."):
+                        found[ev.name] = (ev.start_ns, ev.duration_ns,
+                                          dict(ev.stats))
+    assert found["sim.simulate"][2] == {"run": 5}
+    (s0, d0, _), (s1, d1, _) = found["sim.simulate"], found["sim.loop"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0
+
+
+@pytest.mark.parametrize("engine", ["array", "graph"])
+def test_jax_run_reports_each_phase_span_once(engine):
+    pytest.importorskip("jax")
+    router, dem = _engines()[engine]
+    caps = np.asarray(dem.gbps, dtype=np.float64)
+    with collecting() as mx:
+        inc = flow_incidence(router, dem, "minimal")
+        simulate_incidence(inc, np.full(inc.n_flows, 1 << 20), caps,
+                           backend="jax")
+    timers = mx.snapshot()["timers"]
+    names = ("sim.simulate", "incidence.walk", "incidence.coalesce") + \
+        SIM_PHASES
+    assert set(timers) == set(names)
+    assert all(timers[n]["count"] == 1 for n in names)
+    raw = mx._timers               # unrounded totals
+    assert sum(raw[n]["total_s"] for n in SIM_PHASES) <= \
+        raw["sim.simulate"]["total_s"]
+
+
+def test_jit_rounds_match_numpy_on_staggered_case():
+    pytest.importorskip("jax")
+    inc, size, caps, start = _staggered_case()
+    counts = {}
+    for backend in ("numpy", "jax"):
+        with collecting() as mx:
+            res = simulate_incidence(inc, size, caps, start_s=start,
+                                     backend=backend)
+        counts[backend] = (mx.value("waterfill.rounds"),
+                           mx.value("waterfill.solves"), res.n_epochs)
+    assert counts["jax"] == counts["numpy"]
+    rounds, solves, epochs = counts["jax"]
+    assert solves == epochs and rounds >= epochs > 1
+
+
+def test_event_loop_hlo_carries_every_scope():
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.sim.events import _event_loop_jit
+    from repro.sim.fairshare import _compress_edges
+
+    inc, size, caps, start = _staggered_case()
+    used, edge_c, cap_c = _compress_edges(inc)
+    with jax.enable_x64(True):
+        args = [jnp.asarray(a) for a in (inc.flow, edge_c, inc.frac, cap_c,
+                                         size, caps, start, 1e-9)]
+        lowered = _event_loop_jit().lower(
+            *args, jnp.arange(4), E=used.size, use_pallas=False,
+            record=True, max_j=8)
+    text = lowered.as_text(debug_info=True)
+    missing = [s for s in SCOPES if f"/{s}/" not in text]
+    assert not missing
 
 
 # ------------------------------------------------- trace determinism ----
