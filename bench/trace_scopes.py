@@ -93,11 +93,11 @@ def device_ops(events) -> "dict[str, list[tuple[str, float, float]]]":
     return out
 
 
-def scope_of(tf_op: str, prefixes=SCOPE_PREFIXES) -> str:
+def scope_of(tf_op: str) -> str:
     """The innermost component of ``tf_op`` that starts with one of
-    ``prefixes``, or ``NO_SCOPE``."""
+    ``SCOPE_PREFIXES``, or ``NO_SCOPE``."""
     for part in reversed(tf_op.rstrip(":").split("/")):
-        if part.startswith(prefixes):
+        if part.startswith(SCOPE_PREFIXES):
             return part
     return NO_SCOPE
 
@@ -110,7 +110,7 @@ def _window(events):
     return min(s for s, _ in sims), max(e for _, e in sims)
 
 
-def device_scopes(events, prefixes=SCOPE_PREFIXES) -> "dict | None":
+def device_scopes(events) -> "dict | None":
     """Seconds of device self time per scope in the window, averaged over
     the TPUs, largest first; None when the trace holds no simulation or
     no device operation."""
@@ -123,23 +123,23 @@ def device_scopes(events, prefixes=SCOPE_PREFIXES) -> "dict | None":
         own = tr.self_times([(i, s, e) for i, (_, s, e) in enumerate(ops)],
                             *window)
         for i, t in own.items():
-            key = scope_of(ops[i][0], prefixes)
+            key = scope_of(ops[i][0])
             out[key] = out.get(key, 0.0) + t
     n = len(devices)
     return dict(sorted(((k, v / n * 1e-9) for k, v in out.items()),
                        key=lambda kv: -kv[1]))
 
 
-def idle_by_span(events, prefixes=SPAN_PREFIXES) -> "dict | None":
+def idle_by_span(events) -> "dict | None":
     """Seconds of device idle time in the window charged to the innermost
-    span (names starting with one of ``prefixes``) covering each part of
-    it, averaged over the TPUs, largest first; None as for
+    span (names starting with one of ``SPAN_PREFIXES``) covering each
+    part of it, averaged over the TPUs, largest first; None as for
     :func:`device_scopes`."""
     window, devices = _window(events), device_ops(events)
     if window is None or not devices:
         return None
     w0, w1 = window
-    spans = host_spans(events, prefixes)
+    spans = host_spans(events)
     idle: "dict[str, float]" = {}
     for ops in devices.values():
         iv = np.array([(max(s, w0), min(e, w1)) for _, s, e in ops
