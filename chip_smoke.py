@@ -185,10 +185,10 @@ def phase_c(preset: str = CHURN_TOPO, interpret: bool = False) -> dict:
     inc = flow_incidence(router,
                          uniform_demands(topo, 0.9 * topo.nic_bw_gbps),
                          "minimal")
-    used, edge_c, _ = _compress_edges(inc)
+    used, inc_c, _ = _compress_edges(inc)
     n_seg = int(used.size)
-    vals = jnp.asarray(inc.frac, dtype=jnp.float32)
-    ids = jnp.asarray(edge_c, dtype=jnp.int32)
+    vals = jnp.asarray(inc_c.frac, dtype=jnp.float32)
+    ids = jnp.asarray(inc_c.edge, dtype=jnp.int32)
     out = {"entries": int(vals.shape[0]), "segments": n_seg}
     for name, kernel, ref in (("segment_sum", segment_sum, segment_sum_ref),
                               ("segment_min", segment_min, segment_min_ref)):

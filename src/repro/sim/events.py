@@ -28,7 +28,8 @@ import numpy as np
 from repro.core.netsim import DEFAULT_NET, NetParams, gbps_to_Bps
 from repro.core.routing_vec import DemandArrays
 from repro.telemetry import get_metrics, get_recorder, span
-from .fairshare import (FlowIncidence, _segment_sum, _waterfill_body,
+from .fairshare import (FlowIncidence, SegmentLayout, _compress_edges,
+                        _segment_reductions, _waterfill_body,
                         _waterfill_scale, flow_incidence, max_min_rates,
                         resolve_sim_backend)
 
@@ -333,6 +334,11 @@ def _event_loop_jit():
     while_loop (:func:`repro.sim.fairshare._waterfill_body`), advance to
     the next start/finish event.  Same constants, same branch structure,
     same freeze tolerances — the golden fixtures hold it to 1e-9.
+    ``(flow, edge, frac)`` is the compressed incidence in flow-major
+    order and ``layout`` its :class:`~repro.sim.fairshare.SegmentLayout`
+    (both from :func:`~repro.sim.fairshare._compress_edges`), over which
+    every per-edge sum and the freeze run
+    (:func:`~repro.sim.fairshare._segment_reductions`).
 
     The loop state also counts the water-filling rounds of every epoch's
     solve (``rounds``, int32, numerically inert): the ``waterfill.rounds``
@@ -359,13 +365,15 @@ def _event_loop_jit():
                        static_argnames=("E", "use_pallas", "record",
                                         "max_j"))
     def run(flow, edge, frac, cap_e, size, caps, start, tol, sel=None, *,
-            E: int, use_pallas: bool, record: bool = False,
-            max_j: int = 0):
+            layout: SegmentLayout, E: int, use_pallas: bool,
+            record: bool = False, max_j: int = 0):
         F = size.shape[0]
         eps = 1e-9
         thresh = eps * jnp.maximum(size, 1.0)
+        edge_sums, flows_hit = _segment_reductions(flow, edge, frac,
+                                                   use_pallas, layout)
         wf_cond, wf_body, wf_init = _waterfill_body(
-            flow, edge, frac, cap_e, caps, tol, E, use_pallas)
+            edge_sums, flows_hit, cap_e, caps, tol)
 
         def solve(active):
             rates, unfrozen, _, rounds = jax.lax.while_loop(
@@ -415,8 +423,8 @@ def _event_loop_jit():
                     t2 = t + dt
                     just_done = act & (remaining <= thresh)
                 with jax.named_scope("epoch.edge_bytes"):
-                    edge_bytes = s["edge_bytes"] + _segment_sum(
-                        moved[flow] * frac, edge, E, use_pallas)
+                    edge_bytes = s["edge_bytes"] + edge_sums(
+                        lambda f, w: moved[f] * w)
                 s2 = dict(
                     s, t=t2, remaining=remaining,
                     finish=jnp.where(just_done, t2, s["finish"]),
@@ -428,9 +436,8 @@ def _event_loop_jit():
                     with jax.named_scope("epoch.journal"):
                         idx = jnp.minimum(s["n_epochs"], max_j - 1)
                         okr = s["n_epochs"] < max_j
-                        loads = _segment_sum(
-                            jnp.where(act, rates, 0.0)[flow] * frac, edge,
-                            E, use_pallas)
+                        act_rates = jnp.where(act, rates, 0.0)
+                        loads = edge_sums(lambda f, w: act_rates[f] * w)
                         util = jnp.where(cap_e[sel] > 0,
                                          loads[sel] / cap_e[sel], 0.0)
                         s2["j_t"] = s["j_t"].at[idx].set(
@@ -481,14 +488,11 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
     import jax
     import jax.numpy as jnp
 
-    from .fairshare import _compress_edges
-
     with span("sim.compress"):
         tol = 1e-12 * _waterfill_scale(inc, caps)
-        # solve over the used-edge subset (identical float sequence —
-        # unused edges never saturate) and scatter edge_bytes back at the
-        # end
-        used, edge_c, cap_c = _compress_edges(inc)
+        # solve over the used-edge subset (unused edges never saturate)
+        # in the sorted layout, and scatter edge_bytes back at the end
+        used, inc_c, layout = _compress_edges(inc)
     record = recorder is not None and recorder.link_policy is not None
     if record:
         sel_g = recorder.link_policy.select(inc, caps)
@@ -501,11 +505,13 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
         sel_c, max_j = None, 0
     with jax.enable_x64(True):
         with span("sim.transfer"):
-            args = [jnp.asarray(a) for a in (inc.flow, edge_c, inc.frac,
-                                             cap_c, size, caps, start, tol)]
+            args = [jnp.asarray(a) for a in (inc_c.flow, inc_c.edge,
+                                             inc_c.frac, inc_c.capacity,
+                                             size, caps, start, tol)]
             args.append(jnp.asarray(sel_c) if record else None)
+            layout = SegmentLayout(*map(jnp.asarray, layout))
         with span("sim.loop"):
-            out = _event_loop_jit()(*args, E=used.size,
+            out = _event_loop_jit()(*args, layout=layout, E=used.size,
                                     use_pallas=use_pallas, record=record,
                                     max_j=max_j)
             finish, used_bytes, n_epochs, done, ok = out[:5]
@@ -534,6 +540,8 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
     # one water-filling solve per epoch, as in the reference loop
     mx.inc("waterfill.solves", n_epochs)
     mx.inc("waterfill.rounds", rounds)
+    if not use_pallas:
+        mx.inc("sim.segment_scan")
     return _finalize_result(inc, size, caps, start, finish, edge_bytes,
                             n_epochs, net)
 

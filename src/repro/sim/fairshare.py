@@ -16,11 +16,15 @@ flows freeze.  Three solver paths compute the identical fixpoint:
 ``numpy``   the reference: a Python round loop of ``np.bincount``
             scatter-adds — the pre-jit solver the golden fixtures pin
             (``tests/golden/fairshare_golden.json``).
-``jax``     the whole solve as ONE jitted ``lax.while_loop`` over sparse
-            COO segment ops (``jax.ops.segment_sum``) — no Python
-            round-trip per round, float64 via a ``jax.enable_x64(True)``
-            scope regardless of the global flag.  This is the 65K-NIC
-            path, and the one that runs on a TPU.
+``jax``     the whole solve as ONE jitted ``lax.while_loop`` — no
+            Python round-trip per round, float64 via a
+            ``jax.enable_x64(True)`` scope regardless of the global flag.
+            Its segment reductions run over a layout sorted once on the
+            host (:func:`_compress_edges`): per-edge sums are segmented
+            scans over the edge-major entries, the per-flow freeze an
+            int32 running count over the flow-major ones — no scatter,
+            which a TPU runs serially in its emulated float64.  This is
+            the 65K-NIC path, and the one that runs on a TPU.
 ``pallas``  the same while_loop with the segment reductions lowered to
             the Pallas kernels (:mod:`repro.kernels.segment_fairshare`),
             run by the Pallas interpreter on the CPU.  A TPU refuses it:
@@ -39,6 +43,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
@@ -195,13 +200,14 @@ def max_min_rates(inc: FlowIncidence, rate_caps_gbps: np.ndarray,
     import jax
     import jax.numpy as jnp
 
-    used, edge_c, cap_c = _compress_edges(inc)
+    _, inc_c, layout = _compress_edges(inc)
     with jax.enable_x64(True):
         rates, converged, rounds = _waterfill_jit()(
-            jnp.asarray(inc.flow), jnp.asarray(edge_c),
-            jnp.asarray(inc.frac), jnp.asarray(cap_c),
+            jnp.asarray(inc_c.flow), jnp.asarray(inc_c.edge),
+            jnp.asarray(inc_c.frac), jnp.asarray(inc_c.capacity),
             jnp.asarray(caps), jnp.asarray(active), jnp.asarray(tol),
-            E=used.size, use_pallas=(backend == "pallas"))
+            layout=SegmentLayout(*map(jnp.asarray, layout)),
+            use_pallas=(backend == "pallas"))
         if not bool(converged):
             raise RuntimeError("water-filling failed to converge "
                                f"({F} flows, {inc.n_edges} edges)")
@@ -266,48 +272,180 @@ def _max_min_rates_reference(inc: FlowIncidence, caps: np.ndarray,
 # ---------------------------------------------------------------------------
 
 
+class SegmentLayout(NamedTuple):
+    """A compressed incidence laid out for the jax solve's segment
+    reductions (:func:`_segment_reductions`).
+
+    In edge-major order, edge ``e``'s entries are one run, opened where
+    ``head_e`` is set and closed at ``edge_end[e]``.  In the flow-major
+    COO, flow ``f``'s entries are ``flow_off[f]:flow_off[f + 1]`` (an
+    empty range for a flow with no fabric path)."""
+
+    flow_e: np.ndarray    # (NNZ,) int32 flow of each entry, edge-major
+    frac_e: np.ndarray    # (NNZ,) float64 its fraction, edge-major
+    head_e: np.ndarray    # (NNZ,) bool: the entry opens its edge's run
+    edge_end: np.ndarray  # (E,) int32 last edge-major entry of each edge
+    flow_off: np.ndarray  # (F + 1,) int32 flow-major offsets
+
+
 def _compress_edges(inc: FlowIncidence):
-    """Drop edges no flow crosses before solving.
+    """Drop edges no flow crosses before solving, and lay the entries
+    out for the sorted segment reductions.
 
     An edge with zero incidence weight can never saturate (``wsum = 0``
     keeps it out of ``open_e``), so it contributes nothing to any round's
-    ``delta`` — the solve over the used-edge subset runs the *identical*
-    float sequence.  Fabric edge sets are much larger than any one flow
-    set's footprint (a 65K-NIC fabric has ~72K directed edges; a
-    neighbor-shift flow set touches ~2 per flow), so this is the main
-    constant-factor win of the jit paths.  Returns ``(used_edge_ids,
-    remapped_edge_col, used_capacities)``.
+    ``delta`` — the solve over the used-edge subset runs the same
+    rounds.  Fabric edge sets are much larger than any one flow set's
+    footprint (a 65K-NIC fabric has ~72K directed edges; a
+    neighbor-shift flow set touches ~2 per flow).
+
+    Returns ``(used, inc_c, layout)``: the used edge ids (sorted), the
+    incidence over them in flow-major order (``inc_c.edge`` indexes
+    ``used``; the routers' output is already flow-sorted and passes
+    through unpermuted, an unsorted one is sorted stably), and its
+    :class:`SegmentLayout`.  One host sort, the edge-major ``argsort``,
+    gives ``used``, the compressed edge column and the layout; the
+    order of entries within an edge is the sort's, which moves only the
+    rounding of that edge's sums.
     """
-    used, edge_c = np.unique(inc.edge, return_inverse=True)
-    return used, edge_c.astype(np.int64), inc.capacity[used]
+    flow, edge, frac = inc.flow, inc.edge, inc.frac
+    nnz = flow.shape[0]
+    if nnz and np.any(flow[1:] < flow[:-1]):
+        order = np.argsort(flow, kind="stable")
+        flow, edge, frac = flow[order], edge[order], frac[order]
+    perm = np.argsort(edge.astype(np.int32))  # int32 keys sort faster
+    edge_s = edge[perm]
+    head = np.ones(nnz, dtype=bool)
+    np.not_equal(edge_s[1:], edge_s[:-1], out=head[1:])
+    used = edge_s[head]
+    edge_c = np.empty(nnz, dtype=np.int64)
+    edge_c[perm] = np.cumsum(head) - 1
+    layout = SegmentLayout(
+        flow_e=flow.astype(np.int32)[perm], frac_e=frac[perm], head_e=head,
+        edge_end=np.flatnonzero(np.append(head[1:], nnz > 0)
+                                ).astype(np.int32),
+        flow_off=np.concatenate(([0], np.cumsum(np.bincount(
+            flow, minlength=inc.n_flows)))).astype(np.int32))
+    inc_c = FlowIncidence(flow, edge_c, frac, inc.n_flows,
+                          inc.capacity[used])
+    return used, inc_c, layout
 
 
-def _segment_sum(vals, ids, n_segments: int, use_pallas: bool):
-    """Backend-selected COO scatter-add (traced inside jit).  The Pallas
-    kernel is interpreted on the CPU and compiled everywhere else."""
+_LANES = 128  # a TPU vector register's lanes: the scan's row width
+
+
+def _scan_steps(vals, head, axis: int):
+    """Hillis–Steele inclusive scan along ``axis`` (log2 steps over
+    shifted copies), restarted wherever ``head`` is set; ``head=None``
+    is a plain running sum.  Returns ``(prefix-or of head, sums)``."""
     import jax
+    import jax.numpy as jnp
+
+    n = vals.shape[axis]
+
+    def shift(x, k):
+        pads = [(0, 0)] * x.ndim
+        pads[axis] = (k, 0)
+        return jnp.pad(jax.lax.slice_in_dim(x, 0, n - k, axis=axis), pads)
+
+    k = 1
+    while k < n:
+        if head is None:
+            vals = vals + shift(vals, k)
+        else:
+            vals = jnp.where(head, vals, vals + shift(vals, k))
+            head = head | shift(head, k)
+        k *= 2
+    return head, vals
+
+
+def _segmented_scan(vals, head=None):
+    """Inclusive scan of ``vals`` (traced inside jit), restarted wherever
+    ``head`` is set: each addition joins two partial sums of one run
+    (``head=None``: a plain running sum).
+
+    Blocked over a (rows, 128) view: a scan along each row, then one
+    over the rows' last sums, whose carry is added to the next row up
+    to its first head."""
+    import jax.numpy as jnp
+
+    n = vals.shape[0]
+    if n == 0:
+        return vals
+    rows = -(-n // _LANES)
+    pad = rows * _LANES - n
+    v = jnp.pad(vals, (0, pad)).reshape(rows, _LANES)
+    h = None if head is None else jnp.pad(head, (0, pad)).reshape(rows,
+                                                                  _LANES)
+    h, v = _scan_steps(v, h, axis=1)
+    _, ends = _scan_steps(v[:, -1], None if h is None else h[:, -1], axis=0)
+    carry = jnp.pad(ends[:-1], (1, 0))[:, None]
+    v = v + carry if h is None else jnp.where(h, v, v + carry)
+    return v.reshape(-1)[:n]
+
+
+def _segment_reductions(flow, edge, frac, use_pallas: bool,
+                        layout: SegmentLayout):
+    """The solve's two segment reductions over one compressed incidence
+    (traced inside jit), as ``(edge_sums, flows_hit)``.
+
+    ``edge_sums(entry)`` is the (E,) per-edge sum of ``entry(flow_ids,
+    fracs)``, a value per entry computed from its flow and fraction.
+    The jax path reads a float64 segmented scan over the edge-major
+    entries at each edge's last entry: no scatter, and each sum adds
+    only its own edge's entries (a global prefix sum read as
+    differences would carry every earlier edge's rounding into it).
+
+    ``flows_hit(sat)`` is the (F,) mask of flows with an entry of
+    positive fraction on an edge of the (E,) mask ``sat``: exact, an
+    int32 running count over the flow-major entries compared at the
+    flow offsets.
+
+    The Pallas path keeps its scatter kernel over ``(flow, edge,
+    frac)``; it is interpreted on the CPU and compiled everywhere else.
+    """
+    import jax
+    import jax.numpy as jnp
 
     if use_pallas:
         from repro.kernels.segment_fairshare import segment_sum
 
-        return segment_sum(vals, ids, n_segments,
-                           interpret=jax.default_backend() == "cpu")
-    return jax.ops.segment_sum(vals, ids, num_segments=n_segments)
+        interpret = jax.default_backend() == "cpu"
+        E, F = layout.edge_end.shape[0], layout.flow_off.shape[0] - 1
+
+        def edge_sums(entry):
+            return segment_sum(entry(flow, frac), edge, E,
+                               interpret=interpret)
+
+        def flows_hit(sat):
+            return segment_sum(jnp.where(sat[edge], frac, 0.0), flow, F,
+                               interpret=interpret) > 0
+        return edge_sums, flows_hit
+
+    def edge_sums(entry):
+        vals = entry(layout.flow_e, layout.frac_e)
+        return _segmented_scan(vals, layout.head_e)[layout.edge_end]
+
+    def flows_hit(sat):
+        hits = (sat[edge] & (frac > 0)).astype(jnp.int32)
+        count = jnp.pad(_segmented_scan(hits), (1, 0))[layout.flow_off]
+        return count[1:] > count[:-1]
+
+    return edge_sums, flows_hit
 
 
-def _waterfill_body(flow, edge, frac, cap_e, caps, tol, E: int,
-                    use_pallas: bool):
+def _waterfill_body(edge_sums, flows_hit, cap_e, caps, tol):
     """(cond, body, init-builder) of the water-filling while_loop —
-    shared by the standalone solver and the in-jit event loop.  A round's
-    device work carries three named scopes: ``waterfill.edge_load`` (the
-    live entries' segment sum into edges), ``waterfill.step`` (the
-    uniform raise and the edges' remaining capacity) and
-    ``waterfill.freeze`` (saturated edges' segment sum into flows, and
-    the capped flows)."""
+    shared by the standalone solver and the in-jit event loop, over the
+    reductions of :func:`_segment_reductions`.  A round's device work
+    carries three named scopes: ``waterfill.edge_load`` (the live
+    entries' sum into edges), ``waterfill.step`` (the uniform raise and
+    the edges' remaining capacity) and ``waterfill.freeze`` (the flows
+    on saturated edges, and the capped flows)."""
     import jax
     import jax.numpy as jnp
 
-    F = caps.shape[0]
+    F, E = caps.shape[0], cap_e.shape[0]
 
     def cond(state):
         _, unfrozen, _, i = state
@@ -316,8 +454,7 @@ def _waterfill_body(flow, edge, frac, cap_e, caps, tol, E: int,
     def body(state):
         rates, unfrozen, cap_left, i = state
         with jax.named_scope("waterfill.edge_load"):
-            live = jnp.where(unfrozen[flow], frac, 0.0)
-            wsum = _segment_sum(live, edge, E, use_pallas)
+            wsum = edge_sums(lambda f, w: jnp.where(unfrozen[f], w, 0.0))
         with jax.named_scope("waterfill.step"):
             open_e = wsum > tol
             delta_e = jnp.where(open_e,
@@ -330,8 +467,7 @@ def _waterfill_body(flow, edge, frac, cap_e, caps, tol, E: int,
             cap_left = cap_left - delta * wsum
         with jax.named_scope("waterfill.freeze"):
             sat = open_e & (cap_left <= tol)
-            on_sat = _segment_sum(jnp.where(sat[edge], frac, 0.0), flow, F,
-                                  use_pallas) > 0
+            on_sat = flows_hit(sat)
             capped = rates >= caps - tol
             unfrozen = unfrozen & ~on_sat & ~capped
         return rates, unfrozen, cap_left, i + 1
@@ -352,11 +488,12 @@ def _waterfill_jit():
     import jax
     import jax.numpy as jnp
 
-    @functools.partial(jax.jit, static_argnames=("E", "use_pallas"))
+    @functools.partial(jax.jit, static_argnames=("use_pallas",))
     def solve(flow, edge, frac, cap_e, caps, active, tol, *,
-              E: int, use_pallas: bool):
-        cond, body, init = _waterfill_body(flow, edge, frac, cap_e, caps,
-                                           tol, E, use_pallas)
+              layout: SegmentLayout, use_pallas: bool):
+        cond, body, init = _waterfill_body(
+            *_segment_reductions(flow, edge, frac, use_pallas, layout),
+            cap_e, caps, tol)
         rates, unfrozen, _, i = jax.lax.while_loop(cond, body,
                                                    init(active))
         return rates, jnp.logical_not(unfrozen.any()), i

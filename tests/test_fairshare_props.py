@@ -21,7 +21,8 @@ try:
 except ImportError:  # optional dep — deterministic fallback shim
     from _hypothesis_shim import given, settings, strategies as st
 
-from repro.sim.fairshare import (FlowIncidence, _compress_edges,
+from repro.sim.fairshare import (FlowIncidence, SegmentLayout,
+                                 _compress_edges, _segment_reductions,
                                  max_min_rates)
 
 seed_st = st.integers(0, 10_000)
@@ -164,11 +165,69 @@ def test_infinite_caps_rejected():
 
 def test_compress_edges_preserves_solution():
     inc, caps, active = random_incidence(123)
-    used, edge_c, cap_c = _compress_edges(inc)
-    assert np.array_equal(used[edge_c], inc.edge)
-    assert np.array_equal(cap_c, inc.capacity[used])
-    inc_c = FlowIncidence(flow=inc.flow, edge=edge_c, frac=inc.frac,
-                          n_flows=inc.n_flows, capacity=cap_c)
+    used, inc_c, _ = _compress_edges(inc)
+    # a flow-sorted incidence passes through in its own order
+    assert np.array_equal(inc_c.flow, inc.flow)
+    assert np.array_equal(used[inc_c.edge], inc.edge)
+    assert np.array_equal(inc_c.capacity, inc.capacity[used])
     ref = max_min_rates(inc, caps, active=active, backend="numpy")
     got = max_min_rates(inc_c, caps, active=active, backend="numpy")
     assert np.array_equal(got, ref)
+
+
+def _layout_case(case: str):
+    """An incidence shaped as ``case`` says, with a per-flow weight."""
+    rng = np.random.default_rng(7)
+    F, E, nnz = 300, 60, 1200
+    flow = np.sort(rng.integers(0, F, nnz))
+    edge = rng.integers(0, E, nnz)
+    frac = rng.uniform(0.1, 2.0, nnz)
+    if case == "skewed_edge":
+        # a hot edge carries most entries, as the hotspot mix's does
+        edge[rng.random(nnz) < 0.7] = 3
+    elif case == "empty_flows":
+        # flows with no entries, among them the first and the last
+        keep = (flow % 3 != 0) & (flow != F - 1)
+        flow, edge, frac = flow[keep], edge[keep], frac[keep]
+    elif case == "zero_frac":
+        frac[rng.random(nnz) < 0.3] = 0.0
+        frac[edge == 5] = 0.0       # an edge all of whose entries are 0
+    # coalesced: one entry per (flow, edge), in flow order
+    key = np.unique(flow * E + edge, return_index=True)[1]
+    if case == "unsorted":
+        key = rng.permutation(key)
+    inc = FlowIncidence(flow=flow[key], edge=edge[key], frac=frac[key],
+                        n_flows=F, capacity=rng.uniform(1.0, 9.0, E))
+    return inc, rng.uniform(0.5, 3.0, F), rng.random(E) < 0.3
+
+
+@pytest.mark.parametrize("case", ["flow_sorted", "unsorted", "skewed_edge",
+                                  "empty_flows", "zero_frac"])
+def test_sorted_segment_reductions_match_add_at(case):
+    """The jax path's per-edge float64 sums agree with ``np.add.at`` to
+    1e-12 relative, and its freeze predicate (a flow has an entry of
+    positive fraction on a saturated edge) agrees exactly."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    inc, weight, sat_all = _layout_case(case)
+    used, inc_c, layout = _compress_edges(inc)
+    sat = sat_all[used]
+    want_sum = np.zeros(inc.n_edges)
+    np.add.at(want_sum, inc.edge, weight[inc.flow] * inc.frac)
+    hit = sat_all[inc.edge] & (inc.frac > 0)
+    want_hit = np.zeros(inc.n_flows, dtype=bool)
+    want_hit[inc.flow[hit]] = True
+    with jax.enable_x64(True):
+        edge_sums, flows_hit = _segment_reductions(
+            jnp.asarray(inc_c.flow), jnp.asarray(inc_c.edge),
+            jnp.asarray(inc_c.frac), False,
+            SegmentLayout(*map(jnp.asarray, layout)))
+        w = jnp.asarray(weight)
+        got_sum = np.asarray(jax.jit(
+            lambda: edge_sums(lambda f, fr: w[f] * fr))())
+        got_hit = np.asarray(jax.jit(
+            lambda: flows_hit(jnp.asarray(sat)))())
+    want_sum = want_sum[used]
+    assert np.all(np.abs(got_sum - want_sum) <= 1e-12 * np.abs(want_sum))
+    assert np.array_equal(got_hit, want_hit)

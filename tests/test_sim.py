@@ -25,8 +25,9 @@ from repro.core.netsim import (DEFAULT_NET, gbps_to_Bps, latency_under_load,
                                load_sweep, make_router, pattern_throughput)
 from repro.core.planes import SprayConfig, spray_completion_time, split_chunks
 from repro.core.routing_graph import GraphRouter, graph_uniform_demands
-from repro.core.routing_vec import (VectorizedHyperXRouter, hotspot_demands,
-                                    neighbor_shift_demands, uniform_demands)
+from repro.core.routing_vec import (DemandArrays, VectorizedHyperXRouter,
+                                    hotspot_demands, neighbor_shift_demands,
+                                    uniform_demands)
 from repro.sim import (FailureSpec, FlowIncidence, FlowSpec, degrade_graph,
                        degraded_router, failure_throughput, flow_incidence,
                        max_min_rates, parse_failure_spec,
@@ -173,6 +174,32 @@ def test_max_min_jax_backend_matches_numpy():
     r_np = max_min_rates(inc, caps, backend="numpy")
     r_jx = max_min_rates(inc, caps, backend="jax")
     assert np.abs(r_np - r_jx).max() < 1e-9
+
+
+def test_jax_event_loop_matches_numpy_on_unsorted_graph_incidence():
+    """The graph engine's incidence, its entries shuffled out of flow
+    order (as no graph router promises an order): the jax event loop
+    sorts it itself and agrees with the numpy loop."""
+    pytest.importorskip("jax")
+    router = GraphRouter(DF_SMALL, backend="numpy")
+    dem = graph_uniform_demands(DF_SMALL, 1600.0)
+    rows = np.random.default_rng(2).choice(dem.n, 160, replace=False)
+    inc = flow_incidence(router, DemandArrays(dem.src[rows], dem.dst[rows],
+                                              dem.gbps[rows]), "minimal")
+    order = np.random.default_rng(3).permutation(inc.nnz)
+    inc = FlowIncidence(inc.flow[order], inc.edge[order], inc.frac[order],
+                        inc.n_flows, inc.capacity)
+    assert np.any(inc.flow[1:] < inc.flow[:-1])
+    rng = np.random.default_rng(4)
+    size = rng.uniform(0.2, 1.0, inc.n_flows) * (1 << 20)
+    start = rng.uniform(0.0, 20e-6, inc.n_flows)
+    caps = np.full(inc.n_flows, 1600.0)
+    ref = simulate_incidence(inc, size, caps, start_s=start,
+                             backend="numpy")
+    got = simulate_incidence(inc, size, caps, start_s=start, backend="jax")
+    assert got.n_epochs == ref.n_epochs > 1
+    assert np.allclose(got.finish_s, ref.finish_s, rtol=1e-9, atol=0)
+    assert np.allclose(got.edge_bytes, ref.edge_bytes, rtol=1e-9, atol=0)
 
 
 def test_sim_backend_follows_the_platform(monkeypatch):

@@ -249,6 +249,17 @@ def test_jax_run_reports_each_phase_span_once(engine):
         raw["sim.simulate"]["total_s"]
 
 
+def test_segment_scan_counts_each_jax_simulation():
+    pytest.importorskip("jax")
+    inc, size, caps, start = _staggered_case()
+    with collecting() as mx:
+        for backend in ("jax", "numpy", "jax"):
+            simulate_incidence(inc, size, caps, start_s=start,
+                               backend=backend)
+    assert mx.value("sim.runs") == 3
+    assert mx.value("sim.segment_scan") == 2
+
+
 def test_jit_rounds_match_numpy_on_staggered_case():
     pytest.importorskip("jax")
     inc, size, caps, start = _staggered_case()
@@ -269,16 +280,18 @@ def test_event_loop_hlo_carries_every_scope():
     import jax.numpy as jnp
 
     from repro.sim.events import _event_loop_jit
-    from repro.sim.fairshare import _compress_edges
+    from repro.sim.fairshare import SegmentLayout, _compress_edges
 
     inc, size, caps, start = _staggered_case()
-    used, edge_c, cap_c = _compress_edges(inc)
+    used, inc_c, layout = _compress_edges(inc)
     with jax.enable_x64(True):
-        args = [jnp.asarray(a) for a in (inc.flow, edge_c, inc.frac, cap_c,
-                                         size, caps, start, 1e-9)]
+        args = [jnp.asarray(a) for a in (inc_c.flow, inc_c.edge, inc_c.frac,
+                                         inc_c.capacity, size, caps, start,
+                                         1e-9)]
         lowered = _event_loop_jit().lower(
-            *args, jnp.arange(4), E=used.size, use_pallas=False,
-            record=True, max_j=8)
+            *args, jnp.arange(4),
+            layout=SegmentLayout(*map(jnp.asarray, layout)), E=used.size,
+            use_pallas=False, record=True, max_j=8)
     text = lowered.as_text(debug_info=True)
     missing = [s for s in SCOPES if f"/{s}/" not in text]
     assert not missing

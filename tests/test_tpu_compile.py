@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.segment_fairshare import segment_min, segment_sum
 from repro.sim.events import _event_loop_jit
-from repro.sim.fairshare import _waterfill_jit
+from repro.sim.fairshare import SegmentLayout, _waterfill_jit
 
 F, E, NNZ = 598_302, 71_982, 2_177_262
 HBM_BYTES = 16 * 2**30
@@ -61,6 +61,13 @@ def _solver_shapes(one_chip):
                    caps=((F,), jnp.float64), tol=((), jnp.float64))
 
 
+def _layout_shapes(one_chip):
+    return SegmentLayout(**_shapes(
+        one_chip, flow_e=((NNZ,), jnp.int32), frac_e=((NNZ,), jnp.float64),
+        head_e=((NNZ,), jnp.bool_), edge_end=((E,), jnp.int32),
+        flow_off=((F + 1,), jnp.int32)))
+
+
 def _assert_fits(compiled):
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -74,7 +81,8 @@ def test_waterfill_compiles_for_v5e(one_chip):
         active = _shapes(one_chip, a=((F,), jnp.bool_))["a"]
         compiled = _waterfill_jit().lower(
             s["flow"], s["edge"], s["frac"], s["cap_e"], s["caps"], active,
-            s["tol"], E=E, use_pallas=False).compile()
+            s["tol"], layout=_layout_shapes(one_chip),
+            use_pallas=False).compile()
     _assert_fits(compiled)
 
 
@@ -85,8 +93,8 @@ def test_event_loop_compiles_for_v5e(one_chip):
                     start=((F,), jnp.float64))
         compiled = _event_loop_jit().lower(
             s["flow"], s["edge"], s["frac"], s["cap_e"], t["size"],
-            s["caps"], t["start"], s["tol"], E=E, use_pallas=False,
-            record=False, max_j=0).compile()
+            s["caps"], t["start"], s["tol"], layout=_layout_shapes(one_chip),
+            E=E, use_pallas=False, record=False, max_j=0).compile()
     _assert_fits(compiled)
 
 
