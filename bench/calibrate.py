@@ -48,9 +48,9 @@ def control_readings(resolved: dict, seed: int) -> dict:
     per_sim = []
     for k in range(traffic.period):
         inp = traffic.inputs(k)
-        want = compare.reference_view(plane, config["net"], inp)
+        want = compare.reference_view(plane, config, inp)
         try:
-            got = compare.reference_view(plane, config["net"], inp,
+            got = compare.reference_view(plane, config, inp,
                                          dtype=np.float32)
         except (RuntimeError, FloatingPointError) as e:
             # a control that gives no number has failed

@@ -135,10 +135,12 @@ def numbers(observed: dict, expected: dict) -> dict:
     }
 
 
-def reference_view(plane, net: dict, inp, dtype=np.float64) -> dict:
-    """Run the reference on one simulation's inputs, in ``dtype``."""
-    inc = ref.incidence(plane, inp.src, inp.dst, dtype)
-    res = ref.simulate(inc, inp.size_bytes, inp.gbps, inp.start_s, net)
+def reference_view(plane, config: dict, inp, dtype=np.float64) -> dict:
+    """Run the reference on one simulation's inputs, in ``dtype``, with
+    the configuration's routing mode and net latencies."""
+    inc = ref.incidence(plane, inp.src, inp.dst, dtype, config["routing"])
+    res = ref.simulate(inc, inp.size_bytes, inp.gbps, inp.start_s,
+                       config["net"])
     gbps = inp.gbps.astype(dtype)
     size = inp.size_bytes.astype(dtype)
     bneck = ref.bottleneck_gbps(inc.flow, inc.edge, inc.frac, inc.capacity,

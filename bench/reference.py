@@ -8,11 +8,21 @@ simulation's inputs.  ``dtype`` is float64 for the reference of record;
 the same code in float32 is the control that the comparison must fail
 (``bench/calibrate.py``, ``bench/tests``).
 
-Semantics (those of ``repro.sim``):
+Semantics (those of ``repro.sim`` and ``docs/routing.md``), in the
+routing mode that the configuration states:
 
-* minimal routing spreads a flow evenly over the D! orders in which its
+* ``minimal`` spreads a flow evenly over the D! orders in which its
   mismatched dimensions can be fixed, one hop per mismatched dimension;
-  an in-dimension hop's capacity is ``links_per_dim / (dims - 1)`` ports;
+* ``valiant`` is DAL (Ahn et al., SC'09): a flow with ``m`` mismatched
+  dimensions has ``n_paths = m! + sum over mismatched i of (dims_i - 2)``
+  paths and an equal share ``1 / n_paths`` of its rate on each.  The m!
+  minimal paths are the orders in which its mismatched dimensions can be
+  fixed.  For each mismatched dimension ``i`` and each coordinate ``v``
+  of ``i`` other than the source's and the destination's there is one
+  deroute path: it first moves to ``v`` along ``i``, then fixes every
+  mismatched dimension in index order;
+* in either mode the entries of one flow on one edge are summed, and an
+  in-dimension hop's capacity is ``links_per_dim / (dims - 1)`` ports;
 * max-min fair rates by progressive water-filling: every unfrozen flow
   rises at one pace until an edge saturates or the flow reaches its cap;
 * an event loop that re-solves the rates at every start or finish.
@@ -48,28 +58,76 @@ class RefIncidence:
     max_capacity: float     # largest edge capacity of the whole plane
 
 
-def incidence(plane, src: np.ndarray, dst: np.ndarray,
-              dtype=F64) -> RefIncidence:
-    """Minimal-routing incidence of the demand rows ``src -> dst``."""
-    dims = np.asarray(plane.dims, dtype=np.int64)
-    D, S = dims.size, plane.S
+def _walk(plane, w, f, cur, steps):
+    """(rows, pairs, weights) of the hops of flows ``f`` from coordinates
+    ``cur``, setting each (dimension, coordinates) of ``steps`` in turn;
+    a hop of flow ``g`` carries the share ``w[g]``."""
+    for i, to in steps:
+        move = np.flatnonzero(cur[:, i] != to)
+        if move.size == 0:
+            continue
+        u = plane.ids(cur[move])
+        cur[move, i] = to[move]
+        g = f[move]
+        yield g, u * plane.S + plane.ids(cur[move]), w[g]
+
+
+def _minimal_paths(plane, cs, cd):
+    """The D! dimension orders, each carrying ``1 / D!`` of every flow."""
+    D = len(plane.dims)
+    f = np.arange(cs.shape[0])
+    w = np.full(f.size, 1.0 / math.factorial(D))
+    for order in itertools.permutations(range(D)):
+        yield from _walk(plane, w, f, cs.copy(),
+                         [(i, cd[:, i]) for i in order])
+
+
+def _dal_paths(plane, cs, cd):
+    """Every DAL path, each carrying ``1 / n_paths`` of its flow."""
+    dims = plane.dims
+    mism = cs != cd
+    n_paths = np.array([math.factorial(k) for k in mism.sum(axis=1)],
+                       dtype=np.int64)
+    for i, d in enumerate(dims):
+        n_paths += mism[:, i] * max(d - 2, 0)
+    w = 1.0 / n_paths
+    # minimal paths: every order of each flow's own mismatched dimensions
+    patterns, which = np.unique(mism, axis=0, return_inverse=True)
+    for p, pattern in enumerate(patterns):
+        f = np.flatnonzero(which.reshape(-1) == p)
+        for order in itertools.permutations(np.flatnonzero(pattern)):
+            yield from _walk(plane, w, f, cs[f].copy(),
+                             [(int(i), cd[f, i]) for i in order])
+    # deroutes: to coordinate v along i, then dimensions in index order
+    for i, d in enumerate(dims):
+        for v in range(d):
+            f = np.flatnonzero(mism[:, i] & (cs[:, i] != v) & (cd[:, i] != v))
+            if f.size:
+                yield from _walk(plane, w, f, cs[f].copy(),
+                                 [(i, np.full(f.size, v, dtype=np.int64))]
+                                 + [(j, cd[f, j]) for j in range(len(dims))])
+
+
+# routing mode -> its paths: the modes with a fixed per-flow spread
+# (``adaptive`` re-routes under load and has no static incidence)
+ROUTINGS = {"minimal": _minimal_paths, "valiant": _dal_paths}
+
+
+def incidence(plane, src: np.ndarray, dst: np.ndarray, dtype=F64,
+              routing: str = "minimal") -> RefIncidence:
+    """Incidence of the demand rows ``src -> dst`` under ``routing``."""
+    if routing not in ROUTINGS:
+        raise ValueError(f"no static incidence for routing {routing!r}; "
+                         f"the reference knows {', '.join(ROUTINGS)}")
+    S = plane.S
     mult = np.array([l / (d - 1) if d > 1 else 0.0
                      for d, l in zip(plane.dims, plane.links_per_dim)])
     cs, cd = plane.coords(src), plane.coords(dst)
-    w = 1.0 / math.factorial(D)
-    rows, pairs, hop_dim = [], [], []
-    for order in itertools.permutations(range(D)):
-        cur = cs.copy()
-        for i in order:
-            f = np.flatnonzero(cur[:, i] != cd[:, i])
-            if f.size == 0:
-                continue
-            u = plane.ids(cur[f])
-            cur[f, i] = cd[f, i]
-            v = plane.ids(cur[f])
-            rows.append(f)
-            pairs.append(u * S + v)
-            hop_dim.append(np.full(f.size, i, dtype=np.int64))
+    rows, pairs, weights = [], [], []
+    for f, pair, w in ROUTINGS[routing](plane, cs, cd):
+        rows.append(f)
+        pairs.append(pair)
+        weights.append(w)
     if not rows:
         z = np.zeros(0, dtype=np.int64)
         return RefIncidence(z, z.copy(), np.zeros(0, dtype=dtype),
@@ -77,17 +135,18 @@ def incidence(plane, src: np.ndarray, dst: np.ndarray,
                             int(src.size),
                             float((mult * plane.port_gbps).max()))
     flow = np.concatenate(rows)
-    pair = np.concatenate(pairs)
-    hdim = np.concatenate(hop_dim)
     # one entry per (flow, edge): paths of one flow may share a hop
-    key, inv = np.unique(flow * np.int64(S * S) + pair, return_inverse=True)
+    key, inv = np.unique(flow * np.int64(S * S) + np.concatenate(pairs),
+                         return_inverse=True)
+    del flow
     frac = np.zeros(key.size, dtype=dtype)
-    np.add.at(frac, inv, dtype(w))
+    np.add.at(frac, inv, np.concatenate(weights).astype(dtype))
     flow_u, pair_u = key // (S * S), key % (S * S)
-    dim_of_pair = np.zeros(S * S, dtype=np.int64)
-    dim_of_pair[pair] = hdim
     used, edge = np.unique(pair_u, return_inverse=True)
-    cap = (mult[dim_of_pair[used]] * plane.port_gbps).astype(dtype)
+    # a hop changes one coordinate: that dimension sets its capacity
+    hop_dim = np.argmax(plane.coords(used // S) != plane.coords(used % S),
+                        axis=1)
+    cap = (mult[hop_dim] * plane.port_gbps).astype(dtype)
     return RefIncidence(flow_u, edge.astype(np.int64), frac, cap, used,
                         int(src.size),
                         float((mult * plane.port_gbps).max()))

@@ -5,13 +5,16 @@
 Run from the checkout root.  ``BENCHMARK.json`` names the cell; the cell
 names a configuration (``bench/configs/<name>.json``) and a traffic mix
 (``bench/traffic/<name>.json``); each metric is read by
-``bench/metrics/<name>.py``.  Nothing here knows a cell by name.
+``bench/metrics/<name>.py``.  Nothing here knows a cell by name.  The
+configuration's ``routing`` is the mode that the program and the
+reference both route with.
 
 One run: refuse any platform but a TPU with enough chips, build the
 router, make the mix's pool of inputs, warm up its shapes, then run
 whole simulations back to back for ``--seconds`` (ending on a whole
 cycle of the mix's load levels).  One simulation is the program's
-spec-to-result path: routing incidence (``flow_incidence``), the solver
+spec-to-result path: routing incidence (``flow_incidence`` in the
+configuration's routing mode), the solver
 (``simulate_incidence`` on the ``auto`` backend, which must resolve to
 ``jax``) and the FCT summary.  After the window, a sample of the
 simulations drawn from the seed is compared with the float64 reference
@@ -69,14 +72,30 @@ def resolve(spec: dict, workload: str) -> dict:
                          f"BENCHMARK.json has {sorted(cells)}")
     cell = cells[workload]
     cfg_entry = {c["name"]: c for c in spec["configs"]}[cell["config"]]
+    config = load_json(os.path.join(ROOT, cfg_entry["file"]))
+    routing_of(config)
     return {
         "cell": cell,
-        "config": load_json(os.path.join(ROOT, cfg_entry["file"])),
+        "config": config,
         "mix": load_json(os.path.join(BENCH, "traffic",
                                       cell["traffic"] + ".json")),
         "end_to_end": spec["end_to_end"],
         "per_layer": spec["per_layer"],
     }
+
+
+def routing_of(config: dict) -> str:
+    """The configuration's routing mode; SystemExit for a mode that the
+    benchmark cannot check."""
+    mode = config.get("routing")
+    if mode not in ref.ROUTINGS:
+        raise SystemExit(
+            f"configuration {config.get('name')!r} routes {mode!r}: the "
+            f"benchmark runs only {', '.join(ref.ROUTINGS)}, the modes with "
+            "a fixed per-flow spread of paths that the float64 reference "
+            "knows (adaptive re-routes under load and has no static "
+            "incidence)")
+    return mode
 
 
 def reader(name: str):
@@ -152,7 +171,7 @@ def build_router(config: dict):
 class Simulator:
     """One simulation through the program's public entry points."""
 
-    def __init__(self, router):
+    def __init__(self, router, routing: str):
         from jax.profiler import TraceAnnotation
 
         from repro.core.routing_vec import DemandArrays
@@ -164,6 +183,7 @@ class Simulator:
             raise RuntimeError(f"solver backend 'auto' resolved to {got!r}, "
                                "the benchmark runs 'jax'")
         self.router = router
+        self.routing = routing
         self._ann = TraceAnnotation
         self._dem = DemandArrays
         self._inc = flow_incidence
@@ -176,7 +196,7 @@ class Simulator:
             with ann("bench.incidence"):
                 inc = self._inc(self.router,
                                 self._dem(inp.src, inp.dst, inp.gbps),
-                                "minimal")
+                                self.routing)
             t1 = clock()
             with ann("bench.solve"):
                 res = self._sim(inc, inp.size_bytes, inp.gbps, inp.start_s,
@@ -198,7 +218,8 @@ class Simulator:
         shapes = set()
         for inp in pool:
             inc = self._inc(self.router,
-                            self._dem(inp.src, inp.dst, inp.gbps), "minimal")
+                            self._dem(inp.src, inp.dst, inp.gbps),
+                            self.routing)
             shape = (inc.n_flows, inc.flow.size, np.unique(inc.edge).size)
             if shape not in shapes:
                 shapes.add(shape)
@@ -273,8 +294,9 @@ def _run_cell(resolved, seed, seconds, trace, require_tpu, t_start,
     config, mix = resolved["config"], resolved["mix"]
     plane = Plane.from_config(config)
     traffic = Traffic(mix, plane, seed)
-    sim = Simulator(build_router(config))
+    sim = Simulator(build_router(config), routing_of(config))
     parts["router"] = lap()
+    log(f"routing: {sim.routing} (configuration {config['name']})")
     pool = [traffic.inputs(j) for j in range(traffic.pool)]
     parts["pool"] = lap()
     n_shapes = sim.warm(pool)
@@ -346,7 +368,7 @@ def _run_cell(resolved, seed, seconds, trace, require_tpu, t_start,
     for k, out in sample.items():
         inp = traffic.inputs(k)
         got = observed_view(out, inp, plane)
-        want = compare.reference_view(plane, config["net"], inp)
+        want = compare.reference_view(plane, config, inp)
         nums = compare.numbers(got, want)
         per_sim.append(nums)
         log(f"check k={k} load={inp.load} epochs "

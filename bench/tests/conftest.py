@@ -43,3 +43,11 @@ def spec():
     import run
 
     return run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+
+
+@pytest.fixture(scope="session")
+def dal_config():
+    """``mphx-2p-8x8`` routed with DAL (``valiant``)."""
+    import run
+
+    return run.load_json(os.path.join(HERE, "mphx-2p-8x8-dal.json"))

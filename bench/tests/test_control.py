@@ -15,15 +15,14 @@ from gen import Plane, Traffic
 SEEDS = (2**31 + 5, 2**32 + 17, 3)
 
 
-def _views(small_config, mix, seed, k):
-    plane = Plane.from_config(small_config)
+def _views(config, mix, seed, k):
+    plane = Plane.from_config(config)
     traffic = Traffic(run.load_json(os.path.join(run.BENCH, "traffic",
                                                  mix + ".json")),
                       plane, seed)
     inp = traffic.inputs(k)
-    net = small_config["net"]
-    return (compare.reference_view(plane, net, inp),
-            compare.reference_view(plane, net, inp, dtype=np.float32))
+    return (compare.reference_view(plane, config, inp),
+            compare.reference_view(plane, config, inp, dtype=np.float32))
 
 
 @pytest.mark.parametrize("mix", ["hotspot", "uniform", "churn"])
@@ -36,6 +35,16 @@ def test_float32_control_fails(small_config, mix, seed):
     lim = {k: v[0] for k, v in compare.LIMITS.items()}
     assert nums["incidence_gap"] > lim["incidence_gap"]
     assert nums["finish_gap"] > lim["finish_gap"]
+
+
+@pytest.mark.parametrize("mix", ["hotspot", "uniform", "churn"])
+@pytest.mark.parametrize("seed", SEEDS)
+def test_float32_control_fails_under_dal(dal_config, mix, seed):
+    want, control = _views(dal_config, mix, seed, k=1)
+    nums = compare.numbers(control, want)
+    assert not compare.verdict(nums), nums
+    lim = {k: v[0] for k, v in compare.LIMITS.items()}
+    assert nums["incidence_gap"] > lim["incidence_gap"]
 
 
 @pytest.mark.parametrize("mix", ["hotspot", "churn"])

@@ -1,8 +1,10 @@
 """The harness's machinery on the CPU: every cell of ``BENCHMARK.json``
 resolved from its files and driven through ``run.run_cell`` at
-``mphx-2p-8x8`` (past the look for a chip), the result line's shape,
-and the refusals of the command itself."""
+``mphx-2p-8x8`` (past the look for a chip), the DAL test configuration
+through the same path, the result line's shape, and the refusals of the
+command itself."""
 
+import glob
 import json
 import os
 import shutil
@@ -14,6 +16,7 @@ import pytest
 import run
 
 CELLS = ["mphx4p-hotspot", "mphx4p-uniform", "mphx4p-churn"]
+NICS = {"mphx-4p-86x9": 66564, "mphx-8p-256": 65536}   # paper Table 2
 
 
 def test_every_entry_finds_its_files(spec):
@@ -32,14 +35,40 @@ def test_every_entry_finds_its_files(spec):
 
 
 def test_configurations_are_the_presets(spec):
+    """Every configuration file, those that no cell uses yet too."""
     from repro.experiments.sweep import SWEEP_TOPOLOGIES
 
-    for c in spec["configs"]:
-        cfg = run.load_json(os.path.join(run.ROOT, c["file"]))
+    files = sorted(glob.glob(os.path.join(run.BENCH, "configs", "*.json")))
+    assert {os.path.join(run.ROOT, c["file"]) for c in spec["configs"]} \
+        <= set(files)
+    for path in files:
+        cfg = run.load_json(path)
+        assert os.path.basename(path) == cfg["name"] + ".json"
         topo = SWEEP_TOPOLOGIES[cfg["preset"]]
         plane = run.Plane.from_config(cfg)
         assert plane.S == topo.switches_per_plane
-        assert plane.S * plane.p == 66564     # NICs
+        assert plane.S * plane.p == topo.n_nics == NICS[cfg["name"]]
+        assert run.routing_of(cfg) == cfg["routing"]
+        assert cfg["reduced"] == []
+        run.build_router(cfg)
+
+
+@pytest.mark.parametrize("mode", ["adaptive", "ecmp", None])
+def test_routing_without_static_incidence_is_refused(tmp_path, spec,
+                                                      small_config, mode):
+    """A configuration routed in a mode that the reference cannot check,
+    or in none (``None``: the key left out), is refused while the cell
+    is resolved, before any run."""
+    cfg = {k: v for k, v in small_config.items() if k != "routing"}
+    if mode is not None:
+        cfg["routing"] = mode
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(cfg))
+    bad = dict(spec, configs=[dict(c, file=str(path))
+                              for c in spec["configs"]])
+    with pytest.raises(SystemExit,
+                       match=f"routes {mode!r}.*adaptive re-routes"):
+        run.resolve(bad, CELLS[0])
 
 
 def test_unknown_workload_is_refused(spec):
@@ -49,13 +78,15 @@ def test_unknown_workload_is_refused(spec):
 
 @pytest.mark.parametrize("workload", CELLS)
 @pytest.mark.parametrize("trace", [False, True])
-def test_cell_runs_through_the_harness(x64, spec, small_config, workload,
-                                       trace):
+def test_cell_runs_through_the_harness(x64, capsys, spec, small_config,
+                                       workload, trace):
     resolved = run.resolve(spec, workload)
     resolved["config"] = small_config
     res = run.run_cell(resolved, 2**31 + 4242, 0.2, trace=trace,
                        require_tpu=False)
     assert res["correct"] is True, res["checks"]
+    assert "routing: minimal (configuration mphx-2p-8x8)" in \
+        capsys.readouterr().err
     assert res["failed"] == 0 and res["attempted"] >= 2
     assert res["device"]["platform"] == "cpu"
     assert list(res)[-1] == "checks"
@@ -75,6 +106,22 @@ def test_cell_runs_through_the_harness(x64, spec, small_config, workload,
         if m["name"] in got:
             assert got[m["name"]]["unit"] == m["unit"]
     json.dumps(res)
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_dal_configuration_runs_through_the_harness(x64, capsys, spec,
+                                                    dal_config, workload):
+    """The program routes the configuration's ``valiant`` mode and the
+    reference checks it in the same mode."""
+    resolved = run.resolve(spec, workload)
+    resolved["config"] = dal_config
+    res = run.run_cell(resolved, 2**31 + 4243, 0.2, trace=False,
+                       require_tpu=False)
+    err = capsys.readouterr().err
+    assert res["correct"] is True, res["checks"]
+    assert res["failed"] == 0 and res["attempted"] >= 2
+    assert "routing: valiant (configuration mphx-2p-8x8-dal)" in err
+    assert res["checks"]["incidence_gap"]["value"] <= 1e-15
 
 
 def test_readers_return_nothing_without_readings():

@@ -1,7 +1,10 @@
 """The benchmark's float64 reference and generators against the repo's
-golden fixture and the program's own generators, at ``mphx-2p-8x8``."""
+golden fixture and the program's own generators, at ``mphx-2p-8x8``,
+and its DAL incidence against DAL's paths enumerated one by one."""
 
+import itertools
 import json
+import math
 import os
 
 import numpy as np
@@ -172,3 +175,94 @@ def test_pool_must_hold_whole_cycles(plane):
     with pytest.raises(ValueError, match="pool"):
         Traffic({"pattern": "uniform", "loads": [0.5, 0.9], "pool": 3,
                  "sizes": {"flow_time_s": 1e-4}}, plane, 1)
+
+
+def _dal_paths(dims, cs, cd) -> "list[list[tuple]]":
+    """Every DAL path of one flow, as its switches' coordinates, from the
+    documented semantics: each order of the mismatched dimensions, then
+    for each mismatched dimension ``i`` and each coordinate ``v`` of it
+    that is neither end's, ``v`` along ``i`` first and then every
+    mismatched dimension in index order."""
+    mism = [i for i in range(len(dims)) if cs[i] != cd[i]]
+    paths = []
+    for order in itertools.permutations(mism):
+        cur = list(cs)
+        path = [tuple(cur)]
+        for i in order:
+            cur[i] = cd[i]
+            path.append(tuple(cur))
+        paths.append(path)
+    for i in mism:
+        for v in range(dims[i]):
+            if v in (cs[i], cd[i]):
+                continue
+            cur = list(cs)
+            cur[i] = v
+            path = [tuple(cs), tuple(cur)]
+            for j in range(len(dims)):
+                if cur[j] != cd[j]:
+                    cur[j] = cd[j]
+                    path.append(tuple(cur))
+            paths.append(path)
+    return paths
+
+
+@pytest.mark.parametrize("dims,links", [
+    ((6,), (5,)),
+    ((4, 3), (9, 4)),           # trunked: 3 and 2 links to each neighbour
+    ((3, 3, 2), (2, 2, 1)),
+])
+def test_dal_reference_matches_path_enumeration(dims, links):
+    plane = Plane(2, 4, dims, links, 1600.0)
+    S = plane.S
+    src, dst, _ = uniform_demands(plane, 800.0)
+    inc = ref.incidence(plane, src, dst, routing="valiant")
+    cs, cd = plane.coords(src), plane.coords(dst)
+    want = {}
+    for f in range(src.size):
+        paths = _dal_paths(dims, tuple(cs[f]), tuple(cd[f]))
+        mism = [i for i in range(len(dims)) if cs[f, i] != cd[f, i]]
+        assert len(paths) == (math.factorial(len(mism))
+                              + sum(dims[i] - 2 for i in mism))
+        for path in paths:
+            for a, b in zip(path, path[1:]):
+                u, v = (int(plane.ids(np.array([c]))[0]) for c in (a, b))
+                key = (f, u * S + v)
+                want[key] = want.get(key, 0.0) + 1.0 / len(paths)
+    got = {(int(f), int(inc.pair[e])): float(x)
+           for f, e, x in zip(inc.flow, inc.edge, inc.frac)}
+    assert got.keys() == want.keys()
+    assert max(abs(got[k] - want[k]) for k in want) <= 1e-15
+    # hop capacities as for minimal routing: links / (dims - 1) ports
+    for pair, cap in zip(inc.pair, inc.capacity):
+        a, b = plane.coords(np.array([pair // S, pair % S]))
+        (i,) = np.flatnonzero(a != b)
+        assert cap == links[i] / (dims[i] - 1) * plane.port_gbps
+    assert inc.n_flows == src.size
+
+
+@pytest.mark.parametrize("build", [uniform_demands, neighbor_shift_demands])
+def test_reference_dal_incidence_matches_the_program(plane, build):
+    """The same (flow, edge) entries as the program's array engine in its
+    ``valiant`` mode, and shares within rounding."""
+    from repro.core.hyperx import MPHX
+    from repro.core.netsim import make_router
+    from repro.core.routing_vec import DemandArrays
+    from repro.sim.fairshare import flow_incidence
+
+    topo = MPHX(n=plane.n, p=plane.p, dims=plane.dims)
+    router = make_router(topo, backend="numpy")
+    src, dst, gbps = build(plane, 0.9 * plane.nic_bw_gbps)
+    got = flow_incidence(router, DemandArrays(src, dst, gbps), "valiant")
+    want = ref.incidence(plane, src, dst, routing="valiant")
+    pairs = plane.slot_pairs()
+    assert compare._entry_gap((got.flow, pairs[got.edge], got.frac),
+                              (want.flow, want.pair[want.edge],
+                               want.frac)) <= 1e-15
+
+
+@pytest.mark.parametrize("routing", ["adaptive", "ecmp"])
+def test_reference_refuses_routing_it_does_not_know(plane, routing):
+    src, dst, _ = uniform_demands(plane, 800.0)
+    with pytest.raises(ValueError, match="no static incidence"):
+        ref.incidence(plane, src, dst, routing=routing)
