@@ -256,7 +256,7 @@ class GraphRouter(IncidenceCacheMixin):
                 f"{mode!r} (valiant averages over all intermediates, "
                 "adaptive re-routes under load); use minimal")
         with span("incidence.walk"):
-            self._count_walk()
+            self._count("incidence.walks")
             src = np.asarray(demands.src, dtype=np.int64)
             dst = np.asarray(demands.dst, dtype=np.int64)
             keep = np.flatnonzero(src != dst)

@@ -396,17 +396,12 @@ class IncidenceCacheMixin:
     def metrics(self, registry) -> None:
         self._metrics = registry
 
-    def _count_walk(self) -> None:
+    def _count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to counter ``name`` in this router's registry and in
+        the ambient one."""
         from ..telemetry import get_metrics
-        self.metrics.inc("incidence.walks")
-        get_metrics().inc("incidence.walks")
-
-    def _count_cache(self, hits: int, misses: int) -> None:
-        from ..telemetry import get_metrics
-        ambient = get_metrics()
-        for reg in (self.metrics, ambient):
-            reg.inc("incidence.cache_hits", hits)
-            reg.inc("incidence.cache_misses", misses)
+        for reg in (self.metrics, get_metrics()):
+            reg.inc(name, n)
 
     def _pair_cache(self, mode: str) -> dict:
         if not hasattr(self, "_inc_cache"):
@@ -427,7 +422,8 @@ class IncidenceCacheMixin:
                               return_inverse=True)
         pairs = [tuple(p) for p in uniq.tolist()]
         miss = [p for p in pairs if p not in cache]
-        self._count_cache(hits=len(pairs) - len(miss), misses=len(miss))
+        self._count("incidence.cache_hits", len(pairs) - len(miss))
+        self._count("incidence.cache_misses", len(miss))
         if miss:
             ma = np.asarray(miss, dtype=np.int64)
             sub = DemandArrays(ma[:, 0], ma[:, 1], np.ones(ma.shape[0]))
@@ -577,15 +573,20 @@ class VectorizedHyperXRouter(IncidenceCacheMixin):
         slot ``slot`` — so scatter-adding ``rates[flow] * frac`` over slots
         reproduces :meth:`route`'s loads exactly (the flow simulator's
         steady-state cross-validation, ``tests/test_sim.py``).  ``flow``
-        indexes rows of ``demands``.  Supported modes are the fixed path
+        indexes rows of ``demands``; entries are flow-major and
+        slot-ascending within a flow.  Supported modes are the fixed path
         spreads: ``minimal`` (ordering ECMP) and ``valiant`` (DAL
         deroutes); ``adaptive`` re-routes under load and has no static
-        incidence.  Its two phases are the spans ``incidence.walk`` (the
-        hop walks) and ``incidence.coalesce`` (merging duplicate
-        (flow, slot) entries).
+        incidence.  Its phases are the spans ``incidence.walk`` (the hop
+        walks) and either ``incidence.place`` (DAL on a 1D plane, whose
+        paths share no edge: each entry written straight into its place)
+        or ``incidence.coalesce`` (merging duplicate (flow, slot)
+        entries).  The counters ``incidence.entries`` and
+        ``incidence.merged`` count the entries returned and those the
+        coalesce merged away.
         """
         with span("incidence.walk"):
-            self._count_walk()
+            self._count("incidence.walks")
             src, dst, gbps, cs, cd = self._prep(demands)
             n_full = math.factorial(self.index.D)
             flows, slots_l, fracs = [], [], []
@@ -619,20 +620,76 @@ class VectorizedHyperXRouter(IncidenceCacheMixin):
                     f"no static per-flow incidence for mode {mode!r} "
                     "(adaptive re-routes under load); use minimal or "
                     "valiant")
-        with span("incidence.coalesce"):
-            if not flows:
-                z = np.zeros(0, dtype=np.int64)
-                return z, z.copy(), np.zeros(0)
-            flow = np.concatenate(flows)
-            slot = np.concatenate(slots_l)
-            frac = np.concatenate(fracs)
-            # coalesce duplicate (flow, slot) entries
-            key = flow * np.int64(self.index.n_slots) + slot
-            uniq, inv = np.unique(key, return_inverse=True)
-            out = np.zeros(uniq.size)
-            np.add.at(out, inv, frac)
-            return (uniq // self.index.n_slots, uniq % self.index.n_slots,
-                    out)
+        if mode == "valiant" and self._disjoint_paths():
+            with span("incidence.place"):
+                out = self._place_1d(src, dst, flows, slots_l, fracs)
+            merged = 0
+        else:
+            with span("incidence.coalesce"):
+                out, merged = self._coalesce(flows, slots_l, fracs)
+        self._count("incidence.entries", out[0].size)
+        self._count("incidence.merged", merged)
+        return out
+
+    def _disjoint_paths(self) -> bool:
+        """Whether no two DAL paths of one flow share an edge: true on a
+        1D plane, where a flow s -> d takes the link s->d and, for every
+        other switch v, the links s->v and v->d.  With more dimensions
+        the D! minimal orderings of a flow meet on shared hops, and
+        deroutes rejoin the minimal paths."""
+        return self.index.D == 1
+
+    def _coalesce(self, flows, slots_l, fracs):
+        """Join the walk's entries and sum duplicate (flow, slot) pairs;
+        returns the COO arrays and the number of entries merged away."""
+        if not flows:
+            z = np.zeros(0, dtype=np.int64)
+            return (z, z.copy(), np.zeros(0)), 0
+        flow = np.concatenate(flows)
+        slot = np.concatenate(slots_l)
+        frac = np.concatenate(fracs)
+        key = flow * np.int64(self.index.n_slots) + slot
+        uniq, inv = np.unique(key, return_inverse=True)
+        out = np.zeros(uniq.size)
+        np.add.at(out, inv, frac)
+        return ((uniq // self.index.n_slots, uniq % self.index.n_slots, out),
+                key.size - uniq.size)
+
+    def _place_1d(self, src, dst, flows, slots_l, fracs):
+        """Write the entries of a DAL walk on a 1D plane of ``n`` switches
+        straight into flow-major, slot-ascending COO arrays: the coalesce's
+        output, bit for bit, since no entry has a duplicate to sum.
+
+        A flow s -> d has ``2n - 3`` entries.  Its slots in ascending
+        order are the second hops v->d with v < s, then every s->c
+        (c != s: the minimal hop and the first hops), then the second
+        hops with v > s.  So the rank within the flow of slot ``u*n + c``
+        is ``u - [d < u]``, plus ``c - [s < c]`` where u == s and
+        ``n - 2`` where u > s.
+        """
+        n = int(self.index.dims[0])
+        per_flow = 2 * n - 3
+        n_flows = src.shape[0]
+        emitted = sum(f.size for f in flows)
+        if emitted != n_flows * per_flow:
+            raise RuntimeError(
+                f"the DAL walk emitted {emitted} entries for {n_flows} "
+                f"flows, not {per_flow} a flow: its paths are not the "
+                "disjoint ones that the in-place writer places")
+        first = np.arange(n_flows, dtype=np.int64) * per_flow
+        slot = np.empty(n_flows * per_flow, dtype=np.int64)
+        frac = np.empty(n_flows * per_flow)
+        for f, x, w in zip(flows, slots_l, fracs):
+            s, d = src[f], dst[f]
+            u, c = np.divmod(x, n)
+            rank = u - (d < u)
+            rank += np.where(u == s, c - (s < c), 0)
+            rank += np.where(u > s, n - 2, 0)
+            pos = first[f] + rank
+            slot[pos] = x
+            frac[pos] = w
+        flow = np.repeat(np.arange(n_flows, dtype=np.int64), per_flow)
+        return flow, slot, frac
 
     def mean_switch_hops(self) -> float:
         """Expected switch-switch minimal hops over uniform NIC pairs
