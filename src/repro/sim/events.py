@@ -346,7 +346,13 @@ def _event_loop_jit():
     device work: ``waterfill.edge_load``, ``waterfill.step`` and
     ``waterfill.freeze`` inside a round, ``epoch.admit``,
     ``epoch.advance``, ``epoch.edge_bytes`` and ``epoch.journal`` in an
-    epoch.
+    epoch, and ``loop.edge_bytes`` after the loop.
+
+    Edge bytes are totalled once per simulation: an epoch only adds its
+    moved bytes into the per-flow ``moved_total`` (``epoch.edge_bytes``),
+    and one ``edge_sums`` pass over ``moved_total`` follows the loop
+    (``loop.edge_bytes``).  By distributivity that is the per-epoch sum
+    of the reference loop, its float64 additions reordered.
 
     ``record`` (static) threads the flight-recorder epoch journal —
     per-epoch clock/dt/active-count plus utilization of the ``sel``
@@ -423,13 +429,12 @@ def _event_loop_jit():
                     t2 = t + dt
                     just_done = act & (remaining <= thresh)
                 with jax.named_scope("epoch.edge_bytes"):
-                    edge_bytes = s["edge_bytes"] + edge_sums(
-                        lambda f, w: moved[f] * w)
+                    moved_total = s["moved_total"] + moved
                 s2 = dict(
                     s, t=t2, remaining=remaining,
                     finish=jnp.where(just_done, t2, s["finish"]),
                     stalled=s["stalled"] | stall_set,
-                    edge_bytes=edge_bytes,
+                    moved_total=moved_total,
                     n_epochs=s["n_epochs"] + 1, ok=s["ok"] & conv,
                     rounds=s["rounds"] + rounds)
                 if record:
@@ -459,7 +464,7 @@ def _event_loop_jit():
             "remaining": size,
             "finish": jnp.where(size == 0, start, jnp.inf),
             "stalled": jnp.zeros(F, dtype=bool),
-            "edge_bytes": jnp.zeros(E, dtype=size.dtype),
+            "moved_total": jnp.zeros(F, dtype=size.dtype),
             "n_epochs": jnp.int32(0),
             "rounds": jnp.int32(0),
             "i": jnp.int32(0),
@@ -473,7 +478,9 @@ def _event_loop_jit():
             state["j_util"] = jnp.zeros((max_j, sel.shape[0]),
                                         dtype=size.dtype)
         out = jax.lax.while_loop(cond, body, state)
-        base = (out["finish"], out["edge_bytes"], out["n_epochs"],
+        with jax.named_scope("loop.edge_bytes"):
+            edge_bytes = edge_sums(lambda f, w: out["moved_total"][f] * w)
+        base = (out["finish"], edge_bytes, out["n_epochs"],
                 out["done"], out["ok"])
         if record:
             base += (out["j_t"], out["j_dt"], out["j_act"], out["j_util"])
@@ -542,6 +549,7 @@ def _simulate_incidence_jit(inc: FlowIncidence, size, caps, start,
     mx.inc("waterfill.rounds", rounds)
     if not use_pallas:
         mx.inc("sim.segment_scan")
+    mx.inc("sim.edge_bytes_passes")
     return _finalize_result(inc, size, caps, start, finish, edge_bytes,
                             n_epochs, net)
 

@@ -184,7 +184,7 @@ SIM_PHASES = ("sim.compress", "sim.transfer", "sim.loop", "sim.readback",
               "sim.finalize")
 SCOPES = ("waterfill.edge_load", "waterfill.step", "waterfill.freeze",
           "epoch.admit", "epoch.advance", "epoch.edge_bytes",
-          "epoch.journal")
+          "epoch.journal", "loop.edge_bytes")
 
 
 def test_span_feeds_the_registry_and_nests():
